@@ -433,69 +433,58 @@ def run() -> List[Row]:
 
 def _sharded_row() -> Row:
     """`stream.sharded` (DESIGN.md §11): the greedy streamed workload on
-    a 2-device host mesh (1 data x 2 model head-group shards) vs the
-    single-device baseline, in a forced-device-count subprocess (the XLA
-    flag must precede jax init, so the measurement cannot run in this
-    process).  Asserts-and-reports the serving TP contract: tokens
-    BITWISE the single-device stream's, syncs/token unchanged, and the
+    a 2-device mesh (1 data x 2 model head-group shards) over the first
+    two of `jax.devices()` vs the single-device baseline, in THIS
+    process (one process per chip: a child could not open a device its
+    parent holds).  On a CPU host export
+    XLA_FLAGS=--xla_force_host_platform_device_count=2 (or more) before
+    launch.  Asserts-and-reports the serving TP contract: tokens BITWISE
+    the single-device stream's, syncs/token unchanged, and the
     deterministic AXLE wire accounting (`wire_bytes_per_shard`, guarded
     exact-match by tools/check_bench_regression.py)."""
-    import json as _json
-    import os
-    import subprocess
-    import sys
-    code = (
-        "import os, json, time;"
-        "os.environ['XLA_FLAGS']="
-        "'--xla_force_host_platform_device_count=2';"
-        "import numpy as np;"
-        "from repro.launch.mesh import make_debug_mesh;"
-        "from repro.launch.serve import BatchedServer, Request;"
-        "\n"
-        "def run(mesh):\n"
-        "    s = BatchedServer('starcoder2_3b', smoke=True, batch_slots=2,"
-        " max_seq=64, protocol='bs', stream=True, seg_len=8, mesh=mesh)\n"
-        "    rng = np.random.default_rng(0)\n"
-        "    for i in range(4):\n"
-        "        plen = int(rng.integers(3, 7))\n"
-        "        s.submit(Request(i, rng.integers(1, s.cfg.vocab, plen)"
-        ".astype(np.int32), 16))\n"
-        "    t0 = time.perf_counter(); s.run_until_drained()\n"
-        "    dt = time.perf_counter() - t0\n"
-        "    return s, dt\n"
-        "base, _ = run(None)\n"
-        "mesh, dt = run(make_debug_mesh(1, 2))\n"
-        "bt = {r.rid: list(map(int, r.generated)) for r in base.completed}\n"
-        "mt = {r.rid: list(map(int, r.generated)) for r in mesh.completed}\n"
-        "toks = sum(len(v) for v in mt.values())\n"
-        "print('JSON:' + json.dumps(dict(\n"
-        "    tokens=toks, bitwise=int(bt == mt),\n"
-        "    syncs=mesh.decode_syncs, base_syncs=base.decode_syncs,\n"
-        "    wire=int(mesh.wire_bytes_per_shard),\n"
-        "    base_wire=int(base.wire_bytes_per_shard),\n"
-        "    merges=mesh.wire.merges, dt=dt)))\n"
-    )
-    src = os.path.join(os.path.dirname(__file__), "..", "src")
-    env = dict(os.environ, PYTHONPATH=src)
-    out = subprocess.run([sys.executable, "-c", code], env=env,
-                         capture_output=True, text=True, timeout=1200)
-    assert out.returncode == 0, out.stderr[-2000:]
-    line = [ln for ln in out.stdout.splitlines()
-            if ln.startswith("JSON:")][-1]
-    r = _json.loads(line[len("JSON:"):])
-    assert r["bitwise"] == 1, "sharded stream diverged from single-device"
-    assert r["syncs"] == r["base_syncs"], (r["syncs"], r["base_syncs"])
-    assert r["base_wire"] == 0 and r["wire"] > 0, r
-    toks = r["tokens"]
+    import jax
+    from repro.launch.mesh import make_debug_mesh
+    from repro.launch.serve import BatchedServer, Request
+
+    n_dev = jax.device_count()
+    if n_dev < 2:
+        raise RuntimeError(
+            f"stream.sharded needs 2 devices, {n_dev} visible; on a CPU "
+            "host set XLA_FLAGS=--xla_force_host_platform_device_count=2 "
+            "before launch")
+
+    def run_one(mesh):
+        s = BatchedServer("starcoder2_3b", smoke=True, batch_slots=2,
+                          max_seq=64, protocol="bs", stream=True,
+                          seg_len=8, mesh=mesh)
+        rng = np.random.default_rng(0)
+        for i in range(4):
+            plen = int(rng.integers(3, 7))
+            s.submit(Request(i, rng.integers(1, s.cfg.vocab, plen)
+                             .astype(np.int32), 16))
+        t0 = time.perf_counter()
+        s.run_until_drained()
+        return s, time.perf_counter() - t0
+
+    base, _ = run_one(None)
+    mesh, dt = run_one(make_debug_mesh(1, 2))
+    bt = {r.rid: list(map(int, r.generated)) for r in base.completed}
+    mt = {r.rid: list(map(int, r.generated)) for r in mesh.completed}
+    assert bt == mt, "sharded stream diverged from single-device"
+    assert mesh.decode_syncs == base.decode_syncs, \
+        (mesh.decode_syncs, base.decode_syncs)
+    wire = int(mesh.wire_bytes_per_shard)
+    assert int(base.wire_bytes_per_shard) == 0 and wire > 0, wire
+    toks = sum(len(v) for v in mt.values())
     return (
-        "decode_stream.stream.sharded", r["dt"] / max(1, toks) * 1e6,
+        "decode_stream.stream.sharded", dt / max(1, toks) * 1e6,
         f"tokens={toks};mesh=1x2;"
-        f"decode_syncs={r['syncs']};"
-        f"syncs_per_token={r['syncs'] / max(1, toks):.4f};"
+        f"decode_syncs={mesh.decode_syncs};"
+        f"syncs_per_token={mesh.decode_syncs / max(1, toks):.4f};"
         f"syncs_match_single_device=1;"
         f"tokens_bitwise_single_device=1;"
-        f"wire_bytes_per_shard={r['wire']};"
-        f"wire_merges={r['merges']}")
+        f"wire_bytes_per_shard={wire};"
+        f"wire_merges={mesh.wire.merges}")
 
 
 if __name__ == "__main__":

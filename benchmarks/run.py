@@ -8,8 +8,11 @@ deliverable (d)).
 syncs/token, kernel launches, ...) so the decode fast-path perf
 trajectory is tracked across PRs, e.g.::
 
-    python -m benchmarks.run --only tpu_backstream decode_stream \
+    XLA_FLAGS=--xla_force_host_platform_device_count=4 \
+        python -m benchmarks.run --only tpu_backstream decode_stream \
         --json BENCH_decode.json
+
+(`decode_stream`'s `stream.sharded` row needs two visible devices.)
 """
 from __future__ import annotations
 
@@ -24,6 +27,7 @@ from benchmarks import (decode_stream, fig5_motivation, fig10_runtime,
                         fig15_ooo, fig16_flowctl, roofline_table,
                         tpu_backstream)
 from benchmarks.common import print_rows
+from repro.launch.compile_cache import use_compile_cache
 
 MODULES = (
     ("fig5_motivation", fig5_motivation),
@@ -69,6 +73,7 @@ def main(argv=None) -> int:
     ap.add_argument("--only", nargs="*", default=None,
                     help="run only these modules (default: all)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     modules = MODULES
     if args.only:

@@ -26,6 +26,7 @@ from benchmarks.common import Row, print_rows
 from repro import sharding as sh
 from repro.core.backstream import (OffloadConfig, OffloadProtocol,
                                    decode_attention_combined, use_offload)
+from repro.launch.mesh import make_debug_mesh
 
 B, S, H, KH, HD = 4, 2048, 8, 8, 64
 
@@ -43,8 +44,7 @@ def run() -> List[Row]:
     rows: List[Row] = []
     q, k, v, pos = _mk_inputs()
     n_dev = jax.device_count()
-    mesh = jax.make_mesh((1, n_dev), ("data", "model")) \
-        if n_dev > 1 else None
+    mesh = make_debug_mesh(1, n_dev) if n_dev > 1 else None
     outs = {}
     n_chunks = 4
     for name, proto, fused in (

@@ -16,11 +16,13 @@ offload:
   * `decode_fused_kernel` — ONE-SHOT flash decode: grid (B, KH, n_chunks)
     with the chunk axis innermost and accumulating, so the partial-softmax
     (acc, m, l) statistics live in VMEM scratch across the whole KV
-    sequence and the normalized output is written exactly once.  No
-    per-chunk kernel launches, no (acc, m, l) HBM round trips, no
-    separate XLA merge.  Supports GQA, sliding windows, *per-batch-row*
-    positions (a (B,) pos vector, required for continuous batching where
-    slots sit at different sequence offsets) and an optional extra
+    sequence and the merged statistics are written exactly once, for
+    one elementwise normalization (the oracle's own epilogue).  No
+    per-chunk kernel launches, no per-chunk (acc, m, l) HBM round
+    trips, no separate XLA merge.  Supports GQA, sliding windows,
+    *per-batch-row* positions (a (B,) pos vector, required for
+    continuous batching where slots sit at different sequence offsets),
+    paged and int8 KV pools, and an optional extra
     partial (the current token's own (acc, m, l), merged in the epilogue
     so the cache can stay read-only during the layer scan).
 
@@ -41,7 +43,7 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
+from repro.kernels import ref as _ref
 
 NEG_INF = -1e30
 
@@ -138,7 +140,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
             pltpu.VMEM((blk_q,), jnp.float32),
             pltpu.VMEM((blk_q,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -165,7 +167,7 @@ def _decode_partial_kernel(q_ref, k_ref, v_ref, valid_ref,
     q = q_ref[0, 0].astype(jnp.float32) * scale           # (group, hd)
     k = k_ref[0, 0].astype(jnp.float32)                   # (blk_c, hd)
     v = v_ref[0, 0].astype(jnp.float32)
-    valid = valid_ref[0]                                  # (blk_c,) bool
+    valid = valid_ref[0, 0] != 0                          # (blk_c,)
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -185,8 +187,8 @@ def _decode_partial_kernel(q_ref, k_ref, v_ref, valid_ref,
         acc_ref[0, 0] = acc_s[...]
         # NEG_INF sentinel -> -inf so the merge ignores empty partials.
         m = m_s[...]
-        m_ref[0, 0] = jnp.where(m <= NEG_INF / 2, -jnp.inf, m)
-        l_ref[0, 0] = l_s[...]
+        m_ref[0, 0, 0] = jnp.where(m <= NEG_INF / 2, -jnp.inf, m)
+        l_ref[0, 0, 0] = l_s[...]
 
 
 def decode_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -195,7 +197,12 @@ def decode_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
                              ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """q: (B,1,H,hd); k,v: (B,KH,C,hd) — flash-decoding cache layout;
     valid: (B,C) bool.
-    Returns (acc (B,H,hd) f32, m (B,H) f32, l (B,H) f32)."""
+    Returns (acc (B,H,hd) f32, m (B,H) f32, l (B,H) f32).
+
+    TPU block layout: every block's trailing two dims are either
+    (8, 128)-tiled or the array's own, so the mask rides as (B, 1, C)
+    int32 (a (1, blk_c) lane row per grid cell) and the per-(row, head)
+    m/l outputs as (B, KH, 1, group) (one full (1, group) row)."""
     b, _, h, hd = q.shape
     kh, c = k.shape[1], k.shape[2]
     group = h // kh
@@ -205,8 +212,7 @@ def decode_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
     scale = hd ** -0.5
 
     qt = q[:, 0].reshape(b, kh, group, hd)                # (B,KH,group,hd)
-    kt = k
-    vt = v
+    valid3 = valid.astype(jnp.int32).reshape(b, 1, c)
 
     kernel = functools.partial(_decode_partial_kernel, scale=scale,
                                blk_c=blk_c, n_c=n_c)
@@ -217,27 +223,27 @@ def decode_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
             pl.BlockSpec((1, 1, group, hd), lambda b_, h_, j: (b_, h_, 0, 0)),
             pl.BlockSpec((1, 1, blk_c, hd), lambda b_, h_, j: (b_, h_, j, 0)),
             pl.BlockSpec((1, 1, blk_c, hd), lambda b_, h_, j: (b_, h_, j, 0)),
-            pl.BlockSpec((1, blk_c), lambda b_, h_, j: (b_, j)),
+            pl.BlockSpec((1, 1, blk_c), lambda b_, h_, j: (b_, 0, j)),
         ],
         out_specs=[
             pl.BlockSpec((1, 1, group, hd), lambda b_, h_, j: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, group), lambda b_, h_, j: (b_, h_, 0)),
-            pl.BlockSpec((1, 1, group), lambda b_, h_, j: (b_, h_, 0)),
+            pl.BlockSpec((1, 1, 1, group), lambda b_, h_, j: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, 1, group), lambda b_, h_, j: (b_, h_, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((b, kh, group, hd), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, group), jnp.float32),
-            jax.ShapeDtypeStruct((b, kh, group), jnp.float32),
+            jax.ShapeDtypeStruct((b, kh, 1, group), jnp.float32),
+            jax.ShapeDtypeStruct((b, kh, 1, group), jnp.float32),
         ],
         scratch_shapes=[
             pltpu.VMEM((group, hd), jnp.float32),
             pltpu.VMEM((group,), jnp.float32),
             pltpu.VMEM((group,), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(qt, kt, vt, valid)
+    )(qt, k, v, valid3)
     return (acc.reshape(b, h, hd), m.reshape(b, h), l.reshape(b, h))
 
 
@@ -245,17 +251,23 @@ def decode_attention_partial(q: jax.Array, k: jax.Array, v: jax.Array,
 # Decode: fused one-shot flash decode (produce + merge + normalize)
 # --------------------------------------------------------------------------
 
-def _decode_fused_kernel(pos_ref, q_ref, k_ref, v_ref, *rest,
-                         scale: float, blk_c: int, n_c: int, window: int,
-                         group: int, has_extra: bool,
-                         has_scales: bool = False):
+def _decode_fused_kernel(pos_ref, *rest, scale: float, blk_c: int,
+                         n_c: int, window: int,
+                         group: int, paged: bool, has_extra: bool,
+                         has_scales: bool):
+    # scalar-prefetch operands come first: pos (B,), then the page table
+    rest = list(rest)
+    tbl_ref = rest.pop(0) if paged else None
+    q_ref, k_ref, v_ref = rest[:3]
+    rest = rest[3:]
     if has_scales:
         ks_ref, vs_ref = rest[:2]
         rest = rest[2:]
     if has_extra:
-        acc_e_ref, m_e_ref, l_e_ref, o_ref, acc_s, m_s, l_s = rest
-    else:
-        o_ref, acc_s, m_s, l_s = rest
+        acc_e_ref, m_e_ref, l_e_ref = rest[:3]
+        rest = rest[3:]
+    acc_ref, m_ref, l_ref, acc_s, m_s, l_s = rest
+    b = pl.program_id(0)
     j = pl.program_id(2)          # chunk block (innermost, accumulating)
 
     @pl.when(j == 0)
@@ -264,7 +276,7 @@ def _decode_fused_kernel(pos_ref, q_ref, k_ref, v_ref, *rest,
         m_s[...] = jnp.full_like(m_s, NEG_INF)
         l_s[...] = jnp.zeros_like(l_s)
 
-    pos = pos_ref[0, 0]                                   # this row's offset
+    pos = pos_ref[b]                                      # this row's offset
     q = q_ref[0, 0].astype(jnp.float32) * scale           # (group, hd)
     k = k_ref[0, 0].astype(jnp.float32)                   # (blk_c, hd)
     v = v_ref[0, 0].astype(jnp.float32)
@@ -272,8 +284,9 @@ def _decode_fused_kernel(pos_ref, q_ref, k_ref, v_ref, *rest,
         # int8 KV page: the per-(head, page) scale rides the SAME
         # indirection as the page itself, so dequantization happens in
         # VMEM on the tile just DMA'd — fp pages never exist in HBM.
-        k = k * ks_ref[0, 0, 0]
-        v = v * vs_ref[0, 0, 0]
+        page = tbl_ref[b, j] if paged else j
+        k = k * ks_ref[0, 0, page]
+        v = v * vs_ref[0, 0, page]
 
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
@@ -295,19 +308,22 @@ def _decode_fused_kernel(pos_ref, q_ref, k_ref, v_ref, *rest,
     @pl.when(j == n_c - 1)
     def _finish():
         acc = acc_s[...]
+        m = m_s[...]
         l = l_s[...]
         if has_extra:
             # merge the current token's own (acc, m, l) partial in VMEM —
             # the epilogue of the back-streaming merge, fused in-kernel.
-            m = m_s[...]
-            m_e = m_e_ref[0, 0]
+            m_e = m_e_ref[0, 0, 0]
             mm = jnp.maximum(m, m_e)
             a1 = jnp.exp(m - mm)
             a2 = jnp.exp(m_e - mm)
             acc = acc * a1[:, None] + acc_e_ref[0, 0] * a2[:, None]
-            l = l * a1 + l_e_ref[0, 0] * a2
-        o_ref[0, 0] = (acc
-                       / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
+            l = l * a1 + l_e_ref[0, 0, 0] * a2
+            m = mm
+        acc_ref[0, 0] = acc
+        # NEG_INF sentinel -> -inf so a later merge ignores empty partials
+        m_ref[0, 0, 0] = jnp.where(m <= NEG_INF / 2, -jnp.inf, m)
+        l_ref[0, 0, 0] = l
 
 
 def decode_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
@@ -319,11 +335,31 @@ def decode_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
                            kv_scales: Optional[Tuple[jax.Array, jax.Array]]
                            = None,
                            interpret: bool = False) -> jax.Array:
+    """`decode_attention_fused_partial` followed by the shared
+    normalization epilogue (`ref.normalize_fused_partial`) — the same
+    split as the oracle's, so a head-group-sharded mesh that normalizes
+    after its all_gather reproduces this output bit for bit (DESIGN.md
+    §11).  Returns (B,1,H,hd) q.dtype."""
+    acc, _, l = decode_attention_fused_partial(
+        q, k, v, pos, extra, window=window, blk_c=blk_c, pages=pages,
+        kv_scales=kv_scales, interpret=interpret)
+    return _ref.normalize_fused_partial(acc, l, q.dtype)
+
+
+def decode_attention_fused_partial(
+        q: jax.Array, k: jax.Array, v: jax.Array, pos: jax.Array,
+        extra: Optional[Tuple[jax.Array, jax.Array, jax.Array]] = None,
+        *, window: int = 0, blk_c: int = 128,
+        pages: Optional[jax.Array] = None,
+        kv_scales: Optional[Tuple[jax.Array, jax.Array]] = None,
+        interpret: bool = False
+        ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One-shot flash decode: q (B,1,H,hd) against the whole KV cache
     k/v (B,KH,S,hd), with per-batch-row positions pos (B,) (or a scalar,
     broadcast), masked to slots `pos-window < slot <= pos` (window=0 =>
     no lower bound).  `extra` is an optional (acc (B,H,hd) f32, m (B,H),
-    l (B,H)) partial merged in the epilogue.  Returns (B,1,H,hd) q.dtype.
+    l (B,H)) partial merged in the epilogue.  Returns the merged raw
+    statistics (acc (B,H,hd) f32, m (B,H) f32, l (B,H) f32).
 
     ONE pallas_call for the whole sequence: the chunk axis is the
     innermost grid dimension and (acc, m, l) accumulate in VMEM scratch,
@@ -346,7 +382,13 @@ def decode_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
     f32 — k/v are then int8 pools holding quantized pages and each tile
     is dequantized in VMEM right after its DMA, with the scale fetched
     through the SAME page indirection (DESIGN.md §10).  The scale page
-    width must equal the kernel chunk (enforced below)."""
+    width must equal the kernel chunk (enforced below).
+
+    TPU block layout: per-row scalars (`pos`, the page table) ride
+    scalar prefetch into SMEM, the int8 scales reach SMEM one (row,
+    head)'s page row at a time, and the per-(row, head) `extra`
+    statistics are laid out (B, KH, 1, group) so each block spans the
+    array's own trailing dims."""
     b, _, h, hd = q.shape
     kh, s = k.shape[1], k.shape[2]
     assert h % kh == 0
@@ -369,87 +411,77 @@ def decode_attention_fused(q: jax.Array, k: jax.Array, v: jax.Array,
         assert s % blk_c == 0, (s, blk_c)
         n_c = pages.shape[1]
     scale = hd ** -0.5
+    paged = pages is not None
 
     qt = q[:, 0].reshape(b, kh, group, hd)                # (B,KH,group,hd)
-    pos2 = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1, 1),
-                            (b, 1))
+    pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
 
     kernel = functools.partial(
         _decode_fused_kernel, scale=scale, blk_c=blk_c, n_c=n_c,
-        window=window, group=group, has_extra=extra is not None,
-        has_scales=kv_scales is not None)
+        window=window, group=group, paged=paged,
+        has_extra=extra is not None, has_scales=kv_scales is not None)
 
-    def _maps(paged):
-        # index maps; under scalar prefetch every map takes the table
-        # ref as a trailing argument (only k/v and their scales consult it)
-        if paged:
-            return (lambda b_, h_, j, t: (b_, 0),
-                    lambda b_, h_, j, t: (b_, h_, 0, 0),
-                    lambda b_, h_, j, t: (b_, h_, t[b_, j], 0),
-                    lambda b_, h_, j, t: (b_, h_, 0),
-                    lambda b_, h_, j, t: (b_, h_, 0, 0),
-                    lambda b_, h_, j, t: (b_, h_, t[b_, j]))
-        return (lambda b_, h_, j: (b_, 0),
-                lambda b_, h_, j: (b_, h_, 0, 0),
-                lambda b_, h_, j: (b_, h_, j, 0),
-                lambda b_, h_, j: (b_, h_, 0),
-                lambda b_, h_, j: (b_, h_, 0, 0),
-                lambda b_, h_, j: (b_, h_, j))
+    # index maps take the scalar-prefetch refs (pos, [table]) trailing;
+    # only the k/v chunk map consults the table
+    if paged:
+        def chunk_map(b_, h_, j, pos_ref, t):
+            return (b_, h_, t[b_, j], 0)
+    else:
+        def chunk_map(b_, h_, j, pos_ref):
+            return (b_, h_, j, 0)
 
-    (pos_map, head_map, chunk_map, vec_map, out_map,
-     scale_map) = _maps(pages is not None)
+    def head_map(b_, h_, j, *_):
+        return (b_, h_, 0, 0)
+
     in_specs = [
-        pl.BlockSpec((1, 1), pos_map, memory_space=pltpu.SMEM),
         pl.BlockSpec((1, 1, group, hd), head_map),
         pl.BlockSpec((1, 1, blk_c, hd), chunk_map),
         pl.BlockSpec((1, 1, blk_c, hd), chunk_map),
     ]
-    args = [pos2, qt, k, v]
+    args = [qt, k, v]
     if kv_scales is not None:
-        args += [kv_scales[0].astype(jnp.float32),
-                 kv_scales[1].astype(jnp.float32)]
-        in_specs += [
-            pl.BlockSpec((1, 1, 1), scale_map, memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1, 1), scale_map, memory_space=pltpu.SMEM),
-        ]
+        # one (row, head)'s page scales per grid cell, as a (1, n_pages)
+        # SMEM row; the body picks its page's scalar
+        n_sc = kv_scales[0].shape[2]
+        args += [sc.astype(jnp.float32).reshape(b * kh, 1, n_sc)
+                 for sc in kv_scales]
+        in_specs += [pl.BlockSpec(
+            (1, 1, n_sc), lambda b_, h_, j, *_: (b_ * kh + h_, 0, 0),
+            memory_space=pltpu.SMEM)] * 2
     if extra is not None:
         acc_e, m_e, l_e = extra
         args += [acc_e.astype(jnp.float32).reshape(b, kh, group, hd),
-                 m_e.astype(jnp.float32).reshape(b, kh, group),
-                 l_e.astype(jnp.float32).reshape(b, kh, group)]
+                 m_e.astype(jnp.float32).reshape(b, kh, 1, group),
+                 l_e.astype(jnp.float32).reshape(b, kh, 1, group)]
         in_specs += [
             pl.BlockSpec((1, 1, group, hd), head_map),
-            pl.BlockSpec((1, 1, group), vec_map),
-            pl.BlockSpec((1, 1, group), vec_map),
+            pl.BlockSpec((1, 1, 1, group), head_map),
+            pl.BlockSpec((1, 1, 1, group), head_map),
         ]
 
-    out_specs = pl.BlockSpec((1, 1, group, hd), out_map)
-    out_shape = jax.ShapeDtypeStruct((b, kh, group, hd), q.dtype)
-    scratch_shapes = [
-        pltpu.VMEM((group, hd), jnp.float32),
-        pltpu.VMEM((group,), jnp.float32),
-        pltpu.VMEM((group,), jnp.float32),
-    ]
-    params = _CompilerParams(
-        dimension_semantics=("parallel", "parallel", "arbitrary"))
-
-    if pages is None:
-        out = pl.pallas_call(
-            kernel, grid=(b, kh, n_c), in_specs=in_specs,
-            out_specs=out_specs, out_shape=out_shape,
-            scratch_shapes=scratch_shapes, compiler_params=params,
-            interpret=interpret,
-        )(*args)
-    else:
-        # the page table rides scalar prefetch: resident before the body
-        # runs, visible to the BlockSpec index maps (and prepended to the
-        # kernel signature, where the body has no use for it)
-        grid_spec = pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(b, kh, n_c), in_specs=in_specs,
-            out_specs=out_specs, scratch_shapes=scratch_shapes)
-        out = pl.pallas_call(
-            lambda tbl_ref, *rest: kernel(*rest),
-            grid_spec=grid_spec, out_shape=out_shape,
-            compiler_params=params, interpret=interpret,
-        )(pages.astype(jnp.int32), *args)
-    return out.reshape(b, 1, h, hd)
+    prefetch = [pos_b] + ([pages.astype(jnp.int32)] if paged else [])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=len(prefetch), grid=(b, kh, n_c),
+        in_specs=in_specs,
+        out_specs=[
+            pl.BlockSpec((1, 1, group, hd), head_map),
+            pl.BlockSpec((1, 1, 1, group), head_map),
+            pl.BlockSpec((1, 1, 1, group), head_map),
+        ],
+        scratch_shapes=[
+            pltpu.VMEM((group, hd), jnp.float32),
+            pltpu.VMEM((group,), jnp.float32),
+            pltpu.VMEM((group,), jnp.float32),
+        ])
+    acc, m, l = pl.pallas_call(
+        kernel, grid_spec=grid_spec,
+        out_shape=[
+            jax.ShapeDtypeStruct((b, kh, group, hd), jnp.float32),
+            jax.ShapeDtypeStruct((b, kh, 1, group), jnp.float32),
+            jax.ShapeDtypeStruct((b, kh, 1, group), jnp.float32),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(*prefetch, *args)
+    return acc.reshape(b, h, hd), m.reshape(b, h), l.reshape(b, h)

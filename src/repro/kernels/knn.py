@@ -23,8 +23,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
-
 
 def _knn_kernel(q_ref, x_ref, o_ref):
     q = q_ref[...].astype(jnp.float32)         # (blk_q, D)
@@ -55,7 +53,7 @@ def knn_distances(queries: jax.Array, db: jax.Array, *,
         ],
         out_specs=pl.BlockSpec((blk_q, blk_n), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((q, n), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
     )(queries, db)

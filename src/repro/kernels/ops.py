@@ -1,9 +1,15 @@
 """Public jit'd wrappers for the Pallas kernels.
 
-Dispatch policy: on TPU the Pallas lowering runs natively; on CPU (this
-container) the wrappers fall back to the pure-jnp oracles in `ref.py`
-unless `interpret=True` is requested, which executes the kernel body in
-Pallas interpret mode (the correctness path the tests sweep).
+Dispatch policy: on TPU the Pallas lowering runs natively; on CPU the
+wrappers fall back to the pure-jnp oracles in `ref.py` unless
+`interpret=True` is requested, which executes the kernel body in Pallas
+interpret mode (the correctness path the tests sweep).
+
+Mosaic kernels are never partitioned automatically, so under an active
+serving mesh (`repro.sharding.use_rules`) the entries the model calls
+outside a shard_map run their kernel replicated inside one
+(`_replicated_on_mesh`) — the serving graph around them is
+model-replicated anyway (DESIGN.md §11).
 """
 from __future__ import annotations
 
@@ -12,6 +18,7 @@ from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import knn as _knn
@@ -19,12 +26,33 @@ from repro.kernels import quant as _quant
 from repro.kernels import ref as _ref
 from repro.kernels import sls as _sls
 from repro.kernels import ssd as _ssd
+from repro.sharding import active_rules
 
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _replicated_on_mesh(fn):
+    """Run the jitted kernel entry `fn` inside a fully replicated
+    shard_map when a multi-device serving mesh is active and the Pallas
+    branch will be taken; otherwise call it as is.  The check runs at
+    trace time of the CALLER, outside `fn`'s own jit cache, so a server
+    with a mesh and one without never share a lowering."""
+    @functools.wraps(fn)
+    def wrapped(*args, **kwargs):
+        rules = active_rules()
+        mesh = rules.mesh if rules is not None else None
+        if mesh is None or mesh.size == 1 \
+                or not (_on_tpu() or kwargs.get("interpret", False)):
+            return fn(*args, **kwargs)
+        return jax.shard_map(functools.partial(fn, **kwargs), mesh=mesh,
+                             in_specs=P(), out_specs=P(),
+                             check_vma=False)(*args)
+    return wrapped
+
+
+@_replicated_on_mesh
 @functools.partial(jax.jit, static_argnames=(
     "causal", "window", "blk_q", "blk_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
@@ -50,6 +78,7 @@ def decode_attention_partial(q, k, v, valid, *, blk_c: int = 128,
     return _L.decode_attention_partial(q, k, v, valid)
 
 
+@_replicated_on_mesh
 @functools.partial(jax.jit, static_argnames=("window", "blk_c", "interpret"))
 def decode_attention_fused(q, k, v, pos, extra=None, pages=None,
                            kv_scales=None, *,
@@ -100,33 +129,16 @@ def decode_attention_fused_partial(q, k, v, pos, extra=None, pages=None,
     epilogue recovers the single-device fused output bitwise, because
     every statistic is per-(row, head) independent.
 
-    On TPU (or interpret=True) the producer is the Pallas
-    `decode_attention_partial` raw-partials kernel over the
-    dequantized/logically-gathered panel with the validity clock applied
-    host-side, merged with `extra` via the shared epilogue; on CPU it is
-    the fused oracle's own partial path, so the two dispatches share the
-    reference's math exactly."""
+    On TPU (or interpret=True) the producer is the fused Pallas kernel
+    itself, stopped before its normalization (the single-device entry
+    above runs the same kernel and the same epilogue, so the two agree
+    bitwise on the chip too); on CPU it is the fused oracle's own
+    partial path, so the two dispatches share the reference's math
+    exactly."""
     if _on_tpu() or interpret:
-        if kv_scales is not None:
-            k = _ref.dequantize_kv_pages(k, kv_scales[0])
-            v = _ref.dequantize_kv_pages(v, kv_scales[1])
-        if pages is not None:
-            k = _ref.gather_kv_pages(k, pages, blk_c)
-            v = _ref.gather_kv_pages(v, pages, blk_c)
-        b = q.shape[0]
-        s = k.shape[2]
-        pos_b = jnp.broadcast_to(
-            jnp.asarray(pos, jnp.int32).reshape(-1), (b,))
-        slots = jnp.arange(s)
-        valid = slots[None, :] <= pos_b[:, None]
-        if window > 0:
-            valid &= slots[None, :] > (pos_b - window)[:, None]
-        acc, m, l = _fa.decode_attention_partial(q, k, v, valid,
-                                                 blk_c=blk_c,
-                                                 interpret=interpret)
-        if extra is not None:
-            acc, m, l = _ref.merge_fused_partial_pair(acc, m, l, *extra)
-        return acc, m, l
+        return _fa.decode_attention_fused_partial(
+            q, k, v, pos, extra, window=window, blk_c=blk_c, pages=pages,
+            kv_scales=kv_scales, interpret=interpret)
     page_size = blk_c if pages is not None else 0
     if kv_scales is not None and pages is not None:
         assert blk_c == k.shape[2] // kv_scales[0].shape[2]
@@ -135,6 +147,7 @@ def decode_attention_fused_partial(q, k, v, pos, extra=None, pages=None,
         page_size=page_size, kv_scales=kv_scales)
 
 
+@_replicated_on_mesh
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def quant_matmul(x, qt: "_quant.QTensor", *,
                  interpret: bool = False) -> jax.Array:
@@ -260,6 +273,7 @@ def sls(table, indices, weights=None, *, blk_b: int = 8,
     return _ref.sls_reference(table, indices, weights)
 
 
+@_replicated_on_mesh
 @functools.partial(jax.jit, static_argnames=("blk_s", "interpret"))
 def ssd_scan(x, dt, A, B, C, init_state=None, *, blk_s: int = 128,
              interpret: bool = False) -> Tuple[jax.Array, jax.Array]:

@@ -31,7 +31,6 @@ from jax.experimental.pallas import tpu as pltpu
 from jax.tree_util import GetAttrKey
 
 from repro.kernels import ref as _ref
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
 
 QUANT_BLOCK = _ref.QUANT_BLOCK
 WEIGHT_FORMATS = ("q8_0", "q4_k")
@@ -110,45 +109,60 @@ def dequantize_tensor(qt: QTensor) -> jax.Array:
 # Pallas dequant-fused matmul
 # --------------------------------------------------------------------------
 #
-# Grid (n_m, n_n, nB) with the block axis innermost and accumulating in
-# VMEM scratch: each step DMAs one packed (block, bn) weight tile plus
-# its scale (and min) row, expands it to f32 IN VMEM, and feeds the MXU.
-# Packed bytes are the only weight traffic out of HBM.
+# Grid (n_m, n_n, n_k) with the reduction axis innermost and accumulating
+# in VMEM scratch: each step DMAs KB_STEP packed (block, bn) weight tiles
+# plus their scale (and min) rows, expands them to f32 IN VMEM, and feeds
+# the MXU.  Packed bytes are the only weight traffic out of HBM.
+#
+# TPU block layout: one step covers KB_STEP = 8 quant blocks, i.e. a
+# 256-wide x tile (a lane multiple) and an (8, bn) scale tile (a sublane
+# multiple); a single 32-wide quant block is not a legal x block.
 
-def _q8_matmul_kernel(x_ref, s_ref, q_ref, o_ref, acc_ref, *, n_b: int):
+KB_STEP = 8
+
+
+def _q8_matmul_kernel(x_ref, s_ref, q_ref, o_ref, acc_ref, *, n_k: int):
     kb = pl.program_id(2)
 
     @pl.when(kb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]                                        # (bm, block) f32
-    w = q_ref[0].astype(jnp.float32) * s_ref[...]         # (block, bn)
+    x = x_ref[...]                                        # (bm, KB*block)
+    w = q_ref[...].astype(jnp.float32) * s_ref[...][:, None, :]
+    kbs, block, bn = w.shape                              # (KB, block, bn)
+    w = w.reshape(kbs * block, bn)
     acc_ref[...] += jax.lax.dot(x, w, preferred_element_type=jnp.float32)
 
-    @pl.when(kb == n_b - 1)
+    @pl.when(kb == n_k - 1)
     def _finish():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
 def _q4k_matmul_kernel(x_ref, s_ref, m_ref, q_ref, o_ref, acc_ref, *,
-                       n_b: int):
+                       n_k: int):
     kb = pl.program_id(2)
 
     @pl.when(kb == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    x = x_ref[...]                                        # (bm, block) f32
-    packed = q_ref[0]                                     # (block//2, bn)
-    lo = (packed & 0xF).astype(jnp.float32)
-    hi = (packed >> 4).astype(jnp.float32)
-    hb, bn = lo.shape
-    q = jnp.stack([lo, hi], axis=1).reshape(hb * 2, bn)   # nibble order
-    w = q * s_ref[...] + m_ref[...]                       # (block, bn)
-    acc_ref[...] += jax.lax.dot(x, w, preferred_element_type=jnp.float32)
+    # x arrives nibble-split (see quant_matmul): the step's low-nibble
+    # (even) elements in the first half of the tile, the high-nibble
+    # (odd) ones in the second, so no in-kernel interleave is needed
+    x = x_ref[...]                                        # (bm, KB*block)
+    packed = q_ref[...].astype(jnp.int32)                 # (KB, block//2, bn)
+    sc = s_ref[...][:, None, :]
+    mn = m_ref[...][:, None, :]
+    kbs, hb, bn = packed.shape
+    lo = ((packed & 0xF).astype(jnp.float32) * sc + mn).reshape(kbs * hb, bn)
+    hi = ((packed >> 4).astype(jnp.float32) * sc + mn).reshape(kbs * hb, bn)
+    half = kbs * hb
+    acc_ref[...] += (
+        jax.lax.dot(x[:, :half], lo, preferred_element_type=jnp.float32)
+        + jax.lax.dot(x[:, half:], hi, preferred_element_type=jnp.float32))
 
-    @pl.when(kb == n_b - 1)
+    @pl.when(kb == n_k - 1)
     def _finish():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
@@ -165,41 +179,34 @@ def quant_matmul(x: jax.Array, qt: QTensor, *, blk_m: int = 128,
     block = QUANT_BLOCK
     assert qt.d_in == d, (qt.d_in, d)
 
-    # pad x's input axis with zeros up to the blocked width (padded weight
-    # lanes multiply zero activations, so they contribute nothing even
-    # where q4_k's asymmetric grid dequantizes padding to a nonzero value)
+    # pad x's input axis with zeros up to a whole number of KB_STEP-block
+    # steps (padded weight lanes multiply zero activations, so they
+    # contribute nothing even where q4_k's asymmetric grid dequantizes
+    # padding to a nonzero value; padded blocks get zero scales too)
+    n_bp = -(-n_b // KB_STEP) * KB_STEP
     xf = x.astype(jnp.float32)
-    if n_b * block != d:
-        xf = jnp.concatenate(
-            [xf, jnp.zeros((m, n_b * block - d), jnp.float32)], axis=1)
     bm = min(blk_m, m)
     bn = min(blk_n, n)
     pm, pn = -(-m // bm) * bm, -(-n // bn) * bn
-    if pm != m:
-        xf = jnp.concatenate([xf, jnp.zeros((pm - m, n_b * block),
-                                            jnp.float32)], axis=0)
-    scales = qt.scales
-    quants = qt.quants
-    mins = qt.mins
-    if pn != n:
-        zc = ((0, 0), (0, pn - n))
-        scales = jnp.pad(scales, zc)
-        quants = jnp.pad(quants, ((0, 0), (0, 0), (0, pn - n)))
-        if mins is not None:
-            mins = jnp.pad(mins, zc)
-    grid = (pm // bm, pn // bn, n_b)
+    xf = jnp.pad(xf, ((0, pm - m), (0, n_bp * block - d)))
+    scales = jnp.pad(qt.scales, ((0, n_bp - n_b), (0, pn - n)))
+    quants = jnp.pad(qt.quants, ((0, n_bp - n_b), (0, 0), (0, pn - n)))
+    mins = (jnp.pad(qt.mins, ((0, n_bp - n_b), (0, pn - n)))
+            if qt.mins is not None else None)
+    n_k = n_bp // KB_STEP
+    grid = (pm // bm, pn // bn, n_k)
 
-    x_spec = pl.BlockSpec((bm, block), lambda i, j, kb: (i, kb))
-    s_spec = pl.BlockSpec((1, bn), lambda i, j, kb: (kb, j))
-    q_spec = pl.BlockSpec((1, quants.shape[1], bn),
+    x_spec = pl.BlockSpec((bm, KB_STEP * block), lambda i, j, kb: (i, kb))
+    s_spec = pl.BlockSpec((KB_STEP, bn), lambda i, j, kb: (kb, j))
+    q_spec = pl.BlockSpec((KB_STEP, quants.shape[1], bn),
                           lambda i, j, kb: (kb, 0, j))
     out_spec = pl.BlockSpec((bm, bn), lambda i, j, kb: (i, j))
-    params = _CompilerParams(
+    params = pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))
     scratch = [pltpu.VMEM((bm, bn), jnp.float32)]
 
     if qt.fmt == "q8_0":
-        kernel = functools.partial(_q8_matmul_kernel, n_b=n_b)
+        kernel = functools.partial(_q8_matmul_kernel, n_k=n_k)
         out = pl.pallas_call(
             kernel, grid=grid, in_specs=[x_spec, s_spec, q_spec],
             out_specs=out_spec,
@@ -208,14 +215,18 @@ def quant_matmul(x: jax.Array, qt: QTensor, *, blk_m: int = 128,
             interpret=interpret,
         )(xf, scales, quants)
     elif qt.fmt == "q4_k":
-        kernel = functools.partial(_q4k_matmul_kernel, n_b=n_b)
+        # nibble-split x: within each step, element 2i of every block
+        # moves to the first half and element 2i+1 to the second
+        xs = xf.reshape(pm, n_k, KB_STEP, block // 2, 2)
+        xs = xs.transpose(0, 1, 4, 2, 3).reshape(pm, n_bp * block)
+        kernel = functools.partial(_q4k_matmul_kernel, n_k=n_k)
         out = pl.pallas_call(
             kernel, grid=grid, in_specs=[x_spec, s_spec, s_spec, q_spec],
             out_specs=out_spec,
             out_shape=jax.ShapeDtypeStruct((pm, pn), x.dtype),
             scratch_shapes=scratch, compiler_params=params,
             interpret=interpret,
-        )(xf, scales, mins, quants)
+        )(xs, scales, mins, quants)
     else:
         raise ValueError(f"unknown quant format: {qt.fmt}")
     return out[:m, :n]

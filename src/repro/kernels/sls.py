@@ -23,8 +23,6 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
-
 
 def _sls_kernel(table_ref, idx_ref, w_ref, o_ref, *, blk_b: int, bag_len: int):
     d = o_ref.shape[-1]
@@ -68,7 +66,7 @@ def sls(table: jax.Array, indices: jax.Array,
         ],
         out_specs=pl.BlockSpec((blk_b, d), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((b, d), jnp.float32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(table, indices, weights)

@@ -23,11 +23,10 @@ from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pltpu_compat import CompilerParams as _CompilerParams
-
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
                 y_ref, final_ref, state_s, *, blk_s: int, n_c: int):
+    hi = pl.program_id(1)
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
@@ -35,36 +34,46 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, init_ref,
         state_s[...] = init_ref[0, 0].astype(jnp.float32)
 
     x = x_ref[0, 0].astype(jnp.float32)          # (blk_s, P)
-    dt = dt_ref[0, 0].astype(jnp.float32)        # (blk_s,)
-    a = a_ref[0].astype(jnp.float32)             # scalar (negative)
+    dt = dt_ref[0, 0].astype(jnp.float32)        # (1, blk_s) lane row
+    a = a_ref[hi]                                # scalar (negative)
     bm = b_ref[0].astype(jnp.float32)            # (blk_s, N)
     cm = c_ref[0].astype(jnp.float32)            # (blk_s, N)
     state = state_s[...]                         # (P, N)
 
-    loga = dt * a                                # (blk_s,) all <= 0
-    cum = jnp.cumsum(loga)                       # inclusive
+    ii = lax.broadcasted_iota(jnp.int32, (blk_s, blk_s), 0)
+    jj = lax.broadcasted_iota(jnp.int32, (blk_s, blk_s), 1)
+    tri = jj <= ii
+    # Mosaic lowers neither cumsum nor a vector transpose, so both come
+    # from masked reductions over the (blk_s, blk_s) grid: exact f32
+    # adds (the masked-out lanes add zeros), no matmul rounding.
+    dt_col = jnp.sum(jnp.where(ii == jj, dt, 0.0), axis=1,
+                     keepdims=True)              # (blk_s, 1)
+    loga = dt * a                                # (1, blk_s), all <= 0
+    loga_col = dt_col * a                        # (blk_s, 1)
+    # inclusive prefix sums, as a column and as a row
+    cum_col = jnp.sum(jnp.where(tri, loga, 0.0), axis=1, keepdims=True)
+    cum_row = jnp.sum(jnp.where(ii <= jj, loga_col, 0.0), axis=0,
+                      keepdims=True)
+    cum_last = cum_col[blk_s - 1:, :]            # (1, 1)
 
     # Intra-chunk: y_i += sum_{j<=i} (C_i·B_j) · exp(cum_i − cum_j) · dt_j · x_j
     g = jax.lax.dot_general(cm, bm, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
-    ii = lax.broadcasted_iota(jnp.int32, (blk_s, blk_s), 0)
-    jj = lax.broadcasted_iota(jnp.int32, (blk_s, blk_s), 1)
-    tri = jj <= ii
-    decay = jnp.where(tri, jnp.exp(cum[:, None] - cum[None, :]), 0.0)
-    s_mat = g * decay * dt[None, :]
+    decay = jnp.where(tri, jnp.exp(cum_col - cum_row), 0.0)
+    s_mat = g * decay * dt
     y = jax.lax.dot(s_mat, x, preferred_element_type=jnp.float32)
 
     # Inter-chunk: carried-state contribution, decayed to each position.
     y += jax.lax.dot_general(cm, state, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32) \
-        * jnp.exp(cum)[:, None]
+        * jnp.exp(cum_col)
     y_ref[0, 0] = y.astype(y_ref.dtype)
 
     # State handoff: decay to chunk end, absorb this chunk's updates.
-    w = jnp.exp(cum[-1] - cum) * dt              # (blk_s,)
-    upd = jax.lax.dot_general(x, bm * w[:, None], (((0,), (0,)), ((), ())),
+    w = jnp.exp(cum_last - cum_col) * dt_col     # (blk_s, 1)
+    upd = jax.lax.dot_general(x, bm * w, (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
-    state_s[...] = state * jnp.exp(cum[-1]) + upd
+    state_s[...] = state * jnp.exp(cum_last) + upd
 
     @pl.when(ci == n_c - 1)
     def _finish():
@@ -76,7 +85,11 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
              blk_s: int = 128, interpret: bool = False
              ) -> Tuple[jax.Array, jax.Array]:
     """x: (b,s,h,p); dt: (b,s,h); A: (h,); B,C: (b,s,n).
-    Returns (y (b,s,h,p), final_state (b,h,p,n) f32)."""
+    Returns (y (b,s,h,p), final_state (b,h,p,n) f32).
+
+    TPU block layout: dt rides as (b, h, 1, s) so each cell's block is a
+    (1, blk_s) lane row spanning the array's own sublane dim, and A (one
+    f32 scalar per head) sits whole in SMEM."""
     b, s, h, p = x.shape
     n = B.shape[-1]
     blk_s = min(blk_s, s)
@@ -86,7 +99,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         init_state = jnp.zeros((b, h, p, n), jnp.float32)
 
     xt = x.transpose(0, 2, 1, 3)                 # (b,h,s,p)
-    dtt = dt.transpose(0, 2, 1)                  # (b,h,s)
+    dtt = dt.transpose(0, 2, 1)[:, :, None]      # (b,h,1,s)
 
     kernel = functools.partial(_ssd_kernel, blk_s=blk_s, n_c=n_c)
     y, final = pl.pallas_call(
@@ -94,8 +107,9 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
         grid=(b, h, n_c),
         in_specs=[
             pl.BlockSpec((1, 1, blk_s, p), lambda bi, hi, ci: (bi, hi, ci, 0)),
-            pl.BlockSpec((1, 1, blk_s), lambda bi, hi, ci: (bi, hi, ci)),
-            pl.BlockSpec((1,), lambda bi, hi, ci: (hi,)),
+            pl.BlockSpec((1, 1, 1, blk_s),
+                         lambda bi, hi, ci: (bi, hi, 0, ci)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, blk_s, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, blk_s, n), lambda bi, hi, ci: (bi, ci, 0)),
             pl.BlockSpec((1, 1, p, n), lambda bi, hi, ci: (bi, hi, 0, 0)),
@@ -109,8 +123,8 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
             jax.ShapeDtypeStruct((b, h, p, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(xt, dtt, A, B, C, init_state)
+    )(xt, dtt, A.astype(jnp.float32), B, C, init_state)
     return y.transpose(0, 2, 1, 3), final

@@ -106,6 +106,7 @@ from repro.core.backstream import (HostTier, OffloadConfig, OffloadProtocol,
                                    stream_offload_to_host, use_offload)
 from repro.kernels import ops
 from repro.launch import steps as steps_lib
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import transformer
 from repro.models.registry import get_model
 
@@ -280,9 +281,14 @@ class BatchedServer:
                  evict_after: int = 1, offload_chunks: int = 2,
                  page_size: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
-                 quant: Optional[steps_lib.QuantConfig] = None):
+                 quant: Optional[steps_lib.QuantConfig] = None,
+                 n_layers: Optional[int] = None):
         self.cfg = (get_smoke_config(arch_id) if smoke
                     else get_config(arch_id))
+        if n_layers is not None:
+            # a depth cut: the first `n_layers` layers at the
+            # configuration's own widths
+            self.cfg = dataclasses.replace(self.cfg, n_layers=n_layers)
         self.model = get_model(self.cfg)
         self.batch = batch_slots
         self.max_seq = max_seq
@@ -1316,7 +1322,10 @@ class _null:
 
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__)
-    ap.add_argument("--arch", default="mistral_nemo_12b")
+    ap.add_argument("--arch", default="starcoder2_3b")
+    ap.add_argument("--smoke", action="store_true",
+                    help="serve the arch's tiny test preset instead of "
+                         "its published widths (CPU rehearsal)")
     ap.add_argument("--protocol", default="axle", choices=list(PROTOCOLS))
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--max-new", type=int, default=16)
@@ -1377,6 +1386,7 @@ def main() -> int:
                          "--xla_force_host_platform_device_count=N "
                          "before launch")
     args = ap.parse_args()
+    use_compile_cache()
 
     mesh = None
     if args.mesh is not None:
@@ -1389,8 +1399,8 @@ def main() -> int:
         mesh = make_debug_mesh(n_data, n_model)
 
     rng = np.random.default_rng(0)
-    server = BatchedServer(args.arch, smoke=True, batch_slots=args.slots,
-                           mesh=mesh,
+    server = BatchedServer(args.arch, smoke=args.smoke,
+                           batch_slots=args.slots, mesh=mesh,
                            protocol=args.protocol, stream=args.stream,
                            seg_len=args.seg_len, spec=args.spec,
                            spec_k=args.spec_k, draft_arch=args.draft,
@@ -1461,11 +1471,13 @@ def main() -> int:
     if mesh is not None:
         offl += (f" mesh={args.mesh}"
                  f" wire_bytes_per_shard={server.wire_bytes_per_shard}")
-    print(f"[serve] protocol={args.protocol} mode={mode} "
-          f"sampling={'on' if sampled else 'greedy'} "
+    dev = jax.devices()[0]
+    print(f"[serve] arch={server.cfg.arch_id} protocol={args.protocol} "
+          f"mode={mode} sampling={'on' if sampled else 'greedy'} "
           f"requests={len(server.completed)} tokens={toks} "
           f"steps={server.steps} syncs/token={spt:.3f}{spec}{offl} "
-          f"({toks / dt:.1f} tok/s on CPU)")
+          f"({toks / dt:.1f} tok/s incl. compile; platform={dev.platform} "
+          f"device_kind={dev.device_kind} devices={jax.device_count()})")
     return 0
 
 

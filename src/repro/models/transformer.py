@@ -105,7 +105,11 @@ def init_block_params(cfg: ArchConfig, key) -> Tuple[Params, ...]:
     return tuple(out)
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ArchConfig, key) -> Params:
+    """One jitted program, so each weight's f32 draw fuses into its bf16
+    cast: the peak is the parameters' own bytes, not a stack of f32
+    temporaries (at starcoder2_3b widths those would be ~9 GB)."""
     dt = jnp.dtype(cfg.dtype)
     key, ke, kb = jax.random.split(key, 3)
     # stacked blocks: vmap the per-block init over n_blocks keys

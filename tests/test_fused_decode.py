@@ -103,6 +103,38 @@ def test_fused_extra_partial_epilogue():
                                    atol=1e-4, rtol=1e-4)
 
 
+def test_fused_partial_head_groups_recompose_bitwise():
+    """The mesh decode's producer (DESIGN.md §11): the fused kernel's raw
+    statistics, computed per KV-head group and concatenated over heads,
+    normalize to exactly the single-call output — each (row, head) cell
+    of the kernel is independent of the others, paged int8 pools and the
+    extra partial included (interpret mode)."""
+    b, s, h, kh, hd, page = 2, 128, 8, 2, 32, 32
+    ks = jax.random.split(jax.random.key(5), 6)
+    q = rand(ks[0], (b, 1, h, hd), "bfloat16")
+    k, k_sc = ref.quantize_kv_pages(rand(ks[1], (b, kh, s, hd)), page)
+    v, v_sc = ref.quantize_kv_pages(rand(ks[2], (b, kh, s, hd)), page)
+    pages = jnp.stack([jax.random.permutation(kk, s // page)
+                       for kk in jax.random.split(ks[3], b)])
+    extra = L.single_kv_partial(q, rand(ks[4], (b, 1, kh, hd), "bfloat16"),
+                                rand(ks[5], (b, 1, kh, hd), "bfloat16"))
+    pos = jnp.asarray([s - 1, 40], jnp.int32)
+    whole = fa.decode_attention_fused(
+        q, k, v, pos, extra, blk_c=page, pages=pages,
+        kv_scales=(k_sc, v_sc), interpret=True)
+    g = h // kh
+    parts = [fa.decode_attention_fused_partial(
+        q[:, :, i * g:(i + 1) * g], k[:, i:i + 1], v[:, i:i + 1], pos,
+        tuple(e[:, i * g:(i + 1) * g] for e in extra), blk_c=page,
+        pages=pages, kv_scales=(k_sc[:, i:i + 1], v_sc[:, i:i + 1]),
+        interpret=True) for i in range(kh)]
+    acc = jnp.concatenate([p[0] for p in parts], axis=1)
+    l = jnp.concatenate([p[2] for p in parts], axis=1)
+    merged = ref.normalize_fused_partial(acc, l, q.dtype)
+    assert np.array_equal(np.asarray(merged, np.float32),
+                          np.asarray(whole, np.float32))
+
+
 def test_fused_empty_rows_are_zero():
     """pos = -1 (nothing valid, no extra) must yield exactly zero, not a
     uniform average — the epilogue's l==0 guard."""

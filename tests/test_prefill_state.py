@@ -168,6 +168,34 @@ def test_streamed_serving_matches_whole_sequence_forward(arch_id):
         assert got[r.rid] == tuple(want), (arch_id, r.rid)
 
 
+@pytest.mark.parametrize("arch_id", ["mamba2_370m", "starcoder2_3b"])
+def test_depth_cut_server_matches_whole_sequence_forward(arch_id):
+    """`BatchedServer(n_layers=k)` serves the first k layers of the
+    configuration at its own widths (the depth cut the chip smoke holds
+    to a re-score): everything but the depth is the configuration's, and
+    the streamed tokens equal the cut model's whole-sequence forward."""
+    import dataclasses
+
+    from repro.launch.serve import BatchedServer, Request
+    server = BatchedServer(arch_id, smoke=True, batch_slots=2, max_seq=32,
+                           stream=True, seg_len=4, n_layers=1)
+    cfg, model, params = server.cfg, server.model, server.params
+    assert cfg == dataclasses.replace(get_smoke_config(arch_id), n_layers=1)
+    assert all(leaf.shape[0] == 1
+               for leaf in jax.tree.leaves(params["blocks"]))
+    rng = np.random.default_rng(6)
+    reqs = [Request(i, rng.integers(1, cfg.vocab, int(rng.integers(3, 7))
+                                    ).astype(np.int32), 4)
+            for i in range(3)]
+    for r in reqs:
+        server.submit(r)
+    server.run_until_drained()
+    got = {r.rid: tuple(r.generated) for r in server.completed}
+    for r in reqs:
+        want = _reference_greedy(cfg, model, params, r.prompt, 4)
+        assert got[r.rid] == tuple(want), (arch_id, r.rid)
+
+
 def test_encdec_short_clip_matches_short_reference():
     """Variable encoder lengths (ROADMAP item): a clip SHORTER than
     cfg.enc_len is served without frontend-side padding — the prefill

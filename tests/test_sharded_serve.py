@@ -169,6 +169,25 @@ elif MODE == "misc":
             regimes[f"{arch}@{n}"] = list(
                 partition.serve_head_regime(acfg, p))
     out["regimes"] = regimes
+
+    # Mosaic kernels are never auto-partitioned: under a serving mesh the
+    # ops entries run their kernel replicated inside a shard_map — same
+    # values as the unsharded call (interpret mode stands in for the chip)
+    import jax.numpy as jnp
+    from repro.kernels import ops
+    ks = jax.random.split(jax.random.key(0), 3)
+    q = jax.random.normal(ks[0], (1, 16, 4, 8))
+    kv = [jax.random.normal(kk, (1, 16, 2, 8)) for kk in ks[1:]]
+    fa = jax.jit(lambda *a: ops.flash_attention(*a, interpret=True))
+    plain = fa(q, *kv)
+    with mesh, sh.use_rules(rules):
+        meshed = jax.jit(
+            lambda *a: ops.flash_attention(*a, interpret=True))(q, *kv)
+        jaxpr = str(jax.make_jaxpr(
+            lambda *a: ops.flash_attention(*a, interpret=True))(q, *kv))
+    out["kernel_replicated_bitwise"] = bool(
+        jnp.array_equal(plain, meshed))
+    out["kernel_in_shard_map"] = "shard_map" in jaxpr
 print("JSON:" + json.dumps(out))
 '''
 
@@ -295,6 +314,15 @@ def test_misc_page_split_guard_and_serve_specs():
     assert cell["regimes"]["granite_moe_3b@2"] == [True, True]
     assert cell["regimes"]["granite_moe_3b@4"] == [False, False]
     assert cell["regimes"]["mamba2_370m@2"] == [False, False]
+
+
+def test_kernels_run_replicated_in_shard_map_under_mesh():
+    """An ops kernel entry called under a multi-device serving mesh runs
+    inside a fully replicated shard_map (Mosaic kernels are never
+    auto-partitioned) and returns the unsharded call's values."""
+    r = _cell("misc")
+    assert r["kernel_in_shard_map"]
+    assert r["kernel_replicated_bitwise"]
 
 
 # ---------------------------------------------------------------------------
