@@ -326,10 +326,6 @@ def run() -> List[Row]:
             f"syncs_match_baseline=1;restore_overlapped=1;"
             f"tokens_bitwise_baseline=1;"
             f"evictions={server.evictions};restores={server.restores};"
-            f"restore_dispatch_us="
-            f"{server.restore_dispatch_time / max(1, server.restores) * 1e6:.1f};"
-            f"evict_dispatch_us="
-            f"{server.evict_dispatch_time / max(1, server.evictions) * 1e6:.1f};"
             f"host_tier_mb="
             f"{server.host_tier.bytes_evicted / 2**20:.2f};"
             f"prefix_hit_rate={hits / max(1, admissions):.4f};"
@@ -413,15 +409,12 @@ def run() -> List[Row]:
         assert server.pages_allocated == server.pages_freed \
             and server.pages_resident == 0, arch
         toks = sum(len(r.generated) for r in server.completed)
-        chunk_us = (server.prefill_chunk_time
-                    / max(1, server.prefill_chunks) * 1e6)
         rows.append((
             f"decode_stream.stream.chunked_prefill{suffix}",
             dt / max(1, toks) * 1e6,
             f"tokens={toks};long_prompt={LONG_PROMPT};"
             f"prefill_chunk={PREFILL_CHUNK};"
             f"prefill_chunks={server.prefill_chunks};"
-            f"prefill_chunk_us={chunk_us:.1f};"
             f"decode_syncs={server.decode_syncs};"
             f"baseline_decode_syncs={base.decode_syncs};"
             f"inflight_syncs_match_baseline=1;"

@@ -90,6 +90,7 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import sys
 import time
 from typing import Any, Dict, List, Optional, Tuple
@@ -97,6 +98,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro import sharding as sh
 from repro.configs import get_config, get_smoke_config
@@ -112,6 +114,12 @@ from repro.models.registry import get_model
 
 PROTOCOLS = {"bs": OffloadProtocol.BS, "axle": OffloadProtocol.AXLE,
              "rp": OffloadProtocol.RP}
+
+
+def _span(name: str):
+    """Run the decorated method inside a `name` profiler span (a
+    `TraceAnnotation`: under a microsecond with the profiler off)."""
+    return functools.partial(jax.profiler.annotate_function, name=name)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -176,7 +184,13 @@ class Request:
                 SlotState counters (the per-request numbers the host
                 cannot derive from segment outputs once slots are
                 reused).  None outside speculative mode (or for
-                requests that finished at admission)."""
+                requests that finished at admission).
+    arrival / admitted_at / first_token_at — `time.perf_counter`
+                stamps: when the request arrived (given by the caller;
+                `submit` stamps it when None), when it left the queue for
+                a slot, and when its first token was on the host.  Queue
+                wait is admitted_at − arrival; admission is
+                first_token_at − admitted_at."""
     rid: int
     prompt: np.ndarray            # (prompt_len,) int32
     max_new: int
@@ -189,6 +203,9 @@ class Request:
     # slot was evicted to host RAM and later restored — the stream stays
     # bitwise-identical regardless (asserted in tests/test_cache_offload)
     suspensions: int = 0
+    arrival: Optional[float] = None
+    admitted_at: Optional[float] = None
+    first_token_at: Optional[float] = None
 
 
 def _prefill_bucket(n: int, cap: int) -> int:
@@ -544,7 +561,6 @@ class BatchedServer:
             self.chunk_resume_fn = jax.jit(cp.resume, donate_argnums=(1,))
             self.chunk_plan = cp.plan
         self.prefill_chunks = 0        # chunk forwards dispatched
-        self.prefill_chunk_time = 0.0  # host-side chunk dispatch seconds
         self.evictions = 0
         self.restores = 0
         self.restored_dead = 0         # evicted rows that died in flight
@@ -553,8 +569,6 @@ class BatchedServer:
         self.prefix_misses = 0
         self.prefill_tokens_skipped = 0
         self.prefill_forwards = 0
-        self.evict_dispatch_time = 0.0
-        self.restore_dispatch_time = 0.0
         self.queue: List[Request] = []
         self.active: List[Optional[Request]] = [None] * batch_slots
         # host mirrors of the device SlotState, for dispatch-time budget
@@ -565,7 +579,6 @@ class BatchedServer:
         self.completed: List[Request] = []
         self.steps = 0                 # decode token-steps issued
         self.segments_dispatched = 0
-        self.host_syncs = 0            # every host<->device sync (incl. prefill)
         self.decode_syncs = 0          # syncs attributable to the decode loop
         self.tokens_emitted = 0
         # ---- AXLE wire accounting (DESIGN.md §11) --------------------
@@ -610,6 +623,8 @@ class BatchedServer:
 
     def submit(self, req: Request) -> None:
         req.generated = []
+        if req.arrival is None:
+            req.arrival = time.perf_counter()
         self.queue.append(req)
 
     def _ctx(self):
@@ -657,6 +672,7 @@ class BatchedServer:
         so `allocated == freed` in a drained server (no page leaks)."""
         return int(self.slot_pages.sum())
 
+    @_span("serve.assert_ledger")
     def assert_ledger(self) -> None:
         """The per-tick closure invariant: every page ever charged is
         either freed or resident in a currently-occupied slot, and no
@@ -744,7 +760,8 @@ class BatchedServer:
         if self.prefix is None:
             return self._prefill(slot, req)
         plen = len(req.prompt)
-        hit = self.prefix.lookup(req.prompt)
+        with TraceAnnotation("serve.prefix_lookup"):
+            hit = self.prefix.lookup(req.prompt)
         if hit is not None and hit.length == plen:
             pages = dict(hit.pages.materialize())
             logits = jnp.asarray(pages.pop("logits"))
@@ -800,6 +817,7 @@ class BatchedServer:
 
     # -- host-tier slot eviction / restore (DESIGN.md §8) ------------------
 
+    @_span("serve.evict")
     def suspend_slot(self, slot: int) -> None:
         """Evict one active slot to the host tier: its cache pages (every
         leaf kind) and its SlotState row leave as chunked async host
@@ -814,7 +832,6 @@ class BatchedServer:
         happens within one loop iteration of dispatch)."""
         req = self.active[slot]
         assert req is not None
-        t0 = time.perf_counter()
         with self._ctx(), sh.use_rules(self.rules), use_offload(self.offload):
             pages = dict(self.extract_fn(self.cache, slot, None))
             if self.spec:
@@ -835,8 +852,8 @@ class BatchedServer:
         self.suspended.append(req)
         req.suspensions += 1
         self.evictions += 1
-        self.evict_dispatch_time += time.perf_counter() - t0
 
+    @_span("serve.restore")
     def _restore(self, slot: int, req: Request) -> bool:
         """Re-admit a suspended request from the host tier.  The page
         restore is pure async dispatch — per-chunk `device_put` +
@@ -844,15 +861,13 @@ class BatchedServer:
         (the bench's `stream.restore` rows assert syncs/token is
         unchanged).  Reading the saved SlotState row back for the host
         mirrors is the one blocking step; its async copy was issued at
-        eviction, so by restore time it has long drained (accounted like
-        an admission sync, outside `decode_syncs`).  Returns False —
+        eviction, so by restore time it has long drained (outside
+        `decode_syncs`).  Returns False —
         request complete, slot still free — when the row died in its
         final in-flight segment after eviction (its tokens were still
         delivered; stop-regime rows only)."""
-        t0 = time.perf_counter()
         snap, saved_snap = self.host_tier.pop(req.rid)
-        saved = saved_snap.materialize()
-        self.host_syncs += 1        # the saved-state read (was async)
+        saved = saved_snap.materialize()   # the one blocking read
         if not bool(saved["alive"]):
             if self.spec:
                 # the row died in its final in-flight segment after
@@ -881,7 +896,6 @@ class BatchedServer:
         self._set_pages(slot, self._pages_for(self.positions[slot]))
         self.slot_age[slot] = 0
         self.restores += 1
-        self.restore_dispatch_time += time.perf_counter() - t0
         return True
 
     def _evict_for_demand(self) -> None:
@@ -913,17 +927,22 @@ class BatchedServer:
         sp = req.sampling or GREEDY
         assert len(sp.stop_tokens) <= steps_lib.MAX_STOP_TOKENS, sp
         max_new = sp.max_new if sp.max_new is not None else req.max_new
+        plen = len(req.prompt)
         if self.spec:
             # a verify forward ring-writes up to spec_k junk rows past a
             # row's final position; keep them off the valid prefix
-            assert len(req.prompt) + max_new + self.spec_k <= self.max_seq, \
-                (len(req.prompt), max_new, self.spec_k, self.max_seq)
-        logits = self._admit_prefill(slot, req)
-        # the ledger charges what the clock has covered — the prompt's
-        # pages, just written; the budget's pages are charged only as
-        # decode dispatches actually reach them (see _set_pages)
-        self._set_pages(slot, self._pages_for(len(req.prompt)))
-        return self._finish_admit(slot, req, logits)
+            assert plen + max_new + self.spec_k <= self.max_seq, \
+                (plen, max_new, self.spec_k, self.max_seq)
+        with TraceAnnotation("serve.admit", rid=req.rid, prompt_len=plen,
+                             bucket=_prefill_bucket(plen, self.max_seq)):
+            with TraceAnnotation("serve.prefill_dispatch"):
+                logits = self._admit_prefill(slot, req)
+            # the ledger charges what the clock has covered — the
+            # prompt's pages, just written; the budget's pages are
+            # charged only as decode dispatches actually reach them (see
+            # _set_pages)
+            self._set_pages(slot, self._pages_for(plen))
+            return self._finish_admit(slot, req, logits)
 
     def _finish_admit(self, slot: int, req: Request,
                       logits: jax.Array) -> bool:
@@ -931,18 +950,24 @@ class BatchedServer:
         (`_pump_prefill`) prefill: sample the first token from the last
         prompt position's logits (split #0 of the request's chain — the
         one admission host sync) and seed the device SlotState row.
-        Returns False if the request finished on its first token."""
+        Returns False if the request finished on its first token.
+
+        Spans: `serve.first_token` holds everything the first token needs
+        (the key split, the one-row sampling params, the sample) up to
+        the blocking read, which waits behind any in-flight segment;
+        `serve.seed_slot` holds the device row's seeding after it."""
         sp = req.sampling or GREEDY
         max_new = sp.max_new if sp.max_new is not None else req.max_new
-        key, sub = jax.random.split(jax.random.PRNGKey(sp.seed))
-        samp1 = ops.BatchedSampling(
-            temperature=jnp.full((1,), sp.temperature, jnp.float32),
-            top_k=jnp.full((1,), sp.top_k, jnp.int32),
-            top_p=jnp.full((1,), sp.top_p, jnp.float32),
-            min_p=jnp.full((1,), sp.min_p, jnp.float32))
-        first = int(ops.sample_tokens(logits[None], samp1, sub[None],
-                                      vocab=self.cfg.vocab)[0])
-        self.host_syncs += 1           # the admission sync (was: argmax)
+        with TraceAnnotation("serve.first_token"):
+            key, sub = jax.random.split(jax.random.PRNGKey(sp.seed))
+            samp1 = ops.BatchedSampling(
+                temperature=jnp.full((1,), sp.temperature, jnp.float32),
+                top_k=jnp.full((1,), sp.top_k, jnp.int32),
+                top_p=jnp.full((1,), sp.top_p, jnp.float32),
+                min_p=jnp.full((1,), sp.min_p, jnp.float32))
+            first = int(ops.sample_tokens(logits[None], samp1, sub[None],
+                                          vocab=self.cfg.vocab)[0])
+        req.first_token_at = time.perf_counter()
         req.generated.append(first)
         self.tokens_emitted += 1
         remaining = max_new - 1
@@ -951,13 +976,14 @@ class BatchedServer:
         # the first generated token sits at position len(prompt)
         self.positions[slot] = len(req.prompt)
         self.remaining[slot] = remaining
-        stop = np.full((steps_lib.MAX_STOP_TOKENS,), -1, np.int32)
-        stop[:len(sp.stop_tokens)] = sp.stop_tokens
-        self.state = steps_lib.admit_slot(
-            self.state, slot, token=first, position=len(req.prompt),
-            key=key, remaining=remaining, temperature=sp.temperature,
-            top_k=sp.top_k, top_p=sp.top_p, min_p=sp.min_p,
-            stop=jnp.asarray(stop))
+        with TraceAnnotation("serve.seed_slot"):
+            stop = np.full((steps_lib.MAX_STOP_TOKENS,), -1, np.int32)
+            stop[:len(sp.stop_tokens)] = sp.stop_tokens
+            self.state = steps_lib.admit_slot(
+                self.state, slot, token=first, position=len(req.prompt),
+                key=key, remaining=remaining, temperature=sp.temperature,
+                top_k=sp.top_k, top_p=sp.top_p, min_p=sp.min_p,
+                stop=jnp.asarray(stop))
         return True
 
     # -- chunked admission scheduling (DESIGN.md §9) -----------------------
@@ -977,6 +1003,7 @@ class BatchedServer:
             "next": 0,
         }
 
+    @_span("serve.pump_prefill")
     def _pump_prefill(self) -> None:
         """Dispatch AT MOST ONE prefill chunk — the scheduler's interleave
         invariant: between consecutive decode segments the device sees at
@@ -984,7 +1011,7 @@ class BatchedServer:
         their segment cadence (and `decode_syncs`) bit-for-bit unchanged
         while a long prompt admits.  Chunk forwards are pure async
         dispatch; the only host sync is the final chunk's first-token
-        sample (inside `_finish_admit`, accounted like any admission)."""
+        sample (inside `_finish_admit`, as in any admission)."""
         if not self.prefilling:
             return
         slot = min(self.prefilling)          # deterministic FIFO-by-slot
@@ -993,7 +1020,6 @@ class BatchedServer:
         start, size = st["plan"][st["next"]]
         chunk = np.zeros((self.prefill_chunk,), np.int32)
         chunk[:size] = req.prompt[start:start + size]
-        t0 = time.perf_counter()
         with self._ctx(), sh.use_rules(self.rules), use_offload(self.offload):
             if start == 0:
                 logits, self.cache = self.chunk_first_fn(
@@ -1002,7 +1028,6 @@ class BatchedServer:
                 logits, self.cache = self.chunk_resume_fn(
                     self.params, self.cache, jnp.asarray(chunk), slot,
                     start + size, start)
-        self.prefill_chunk_time += time.perf_counter() - t0
         self.prefill_chunks += 1
         # charge the pages this chunk's rows just landed in
         self._set_pages(slot, self._pages_for(start + size))
@@ -1020,6 +1045,7 @@ class BatchedServer:
             self.completed.append(req)       # finished on its first token
             self._free_pages(slot)
 
+    @_span("serve.fill_slots")
     def _fill_slots(self) -> None:
         """Admit work into free slots: restore suspended requests first
         (FIFO — they were admitted before anything still queued), then
@@ -1049,6 +1075,7 @@ class BatchedServer:
                     self.completed.append(req)   # died while evicted
             elif self.queue:
                 req = self.queue.pop(0)
+                req.admitted_at = time.perf_counter()
                 if self.prefill_chunk is not None \
                         and len(req.prompt) > self.prefill_chunk:
                     # long prompt: admit in chunks interleaved with the
@@ -1063,6 +1090,7 @@ class BatchedServer:
                     self.active[s] = None
                     self._free_pages(s)
 
+    @_span("serve.dispatch_rows")
     def _dispatch_rows(self, seg_len: int):
         """Slot accounting at dispatch time, where it is still possible:
         a row with NO stop tokens terminates only by budget, so its token
@@ -1157,22 +1185,26 @@ class BatchedServer:
         if all(r is None for r in self.active):
             return
         rows, plain = self._dispatch_rows(1)
-        with self._ctx(), sh.use_rules(self.rules), use_offload(self.offload):
+        with self._ctx(), sh.use_rules(self.rules), \
+                use_offload(self.offload), TraceAnnotation(
+                    "serve.segment_dispatch", live_rows=len(rows),
+                    plain=plain):
             if self.spec:
                 fn = self.spec_step_plain_fn if plain else self.spec_step_fn
                 seg, emit, alens, self.state, self.cache, \
                     self.draft_cache = fn(
                         self.params, self.draft_params, self.cache,
                         self.draft_cache, self.state)
-                self.steps += self.spec_k + 1
-                self.wire.charge_merges(self._merges_per_spec_round)
-                self._consume_segment(seg, emit, self.state, rows,
-                                      alens=alens)
-                self.assert_ledger()
-                return
-            fn = self.step_plain_fn if plain else self.step_fn
-            seg, emit, self.state, self.cache = fn(
-                self.params, self.cache, self.state)
+            else:
+                fn = self.step_plain_fn if plain else self.step_fn
+                seg, emit, self.state, self.cache = fn(
+                    self.params, self.cache, self.state)
+        if self.spec:
+            self.steps += self.spec_k + 1
+            self.wire.charge_merges(self._merges_per_spec_round)
+            self._consume_segment(seg, emit, self.state, rows, alens=alens)
+            self.assert_ledger()
+            return
         self.steps += 1
         self.wire.charge_merges(self._merges_per_step)
         self._consume_segment(seg, emit, self.state, rows)
@@ -1199,7 +1231,9 @@ class BatchedServer:
                     and any(r is not None for r in self.active):
                 rows, plain = self._dispatch_rows(self.seg_len)
                 with self._ctx(), sh.use_rules(self.rules), \
-                        use_offload(self.offload):
+                        use_offload(self.offload), TraceAnnotation(
+                            "serve.segment_dispatch", live_rows=len(rows),
+                            plain=plain):
                     if self.spec:
                         fn = (self.spec_segment_plain_fn if plain
                               else self.spec_segment_fn)
@@ -1241,6 +1275,7 @@ class BatchedServer:
                     and all(r is None for r in self.active):
                 return
 
+    @_span("serve.consume")
     def _consume_segment(self, seg, emit, state, rows,
                          alens=None) -> None:
         """Deliver one segment's tokens and apply the device's termination
@@ -1263,44 +1298,45 @@ class BatchedServer:
         fetch = (seg, emit, state.alive, state.remaining, state.positions)
         if alens is not None:
             fetch += (alens, state.accepted, state.proposed)
-        got = jax.device_get(fetch)
+        with TraceAnnotation("serve.consume.fetch"):
+            got = jax.device_get(fetch)
         arr, em, alive, rem, pos = got[:5]
         if alens is not None:
             al, acc, prop = got[5:]
-        self.host_syncs += 1
         self.decode_syncs += 1
-        for s, (req, take) in rows.items():
-            toks = arr[s][em[s].astype(bool)]
-            for t in toks:
-                req.generated.append(int(t))
-            self.tokens_emitted += len(toks)
-            if alens is not None:
-                m_r = em[s].reshape(al.shape[1], -1).sum(axis=1)
-                self.draft_proposed += int((m_r > 0).sum()) * self.spec_k
-                self.draft_accepted += int(np.minimum(m_r, al[s]).sum())
-            if take is not None:
-                # device budget accounting must agree with the host's
-                # dispatch-time prediction for stop-free rows
-                assert len(toks) == take, (s, len(toks), take)
-            if self.active[s] is req:
-                # per-row position clock: advances by exactly one per
-                # emitted token, never for frozen rows
-                assert pos[s] == self.positions[s] + len(toks), \
-                    (s, pos[s], self.positions[s], len(toks))
-                self.positions[s] = int(pos[s])
-                # trim the dispatch-time worst-case charge back to the
-                # pages the clock actually reached (a no-op for budget
-                # rows, a release for early-stopped / frozen rows)
-                self._set_pages(s, self._pages_for(self.positions[s]))
-                if take is None:
-                    self.remaining[s] = int(rem[s])
-                    if not alive[s]:
-                        if alens is not None:
-                            req.spec_accepted = int(acc[s])
-                            req.spec_proposed = int(prop[s])
-                        self.completed.append(req)
-                        self.active[s] = None
-                        self._free_pages(s)
+        with TraceAnnotation("serve.consume.deliver"):
+            for s, (req, take) in rows.items():
+                toks = arr[s][em[s].astype(bool)]
+                for t in toks:
+                    req.generated.append(int(t))
+                self.tokens_emitted += len(toks)
+                if alens is not None:
+                    m_r = em[s].reshape(al.shape[1], -1).sum(axis=1)
+                    self.draft_proposed += int((m_r > 0).sum()) * self.spec_k
+                    self.draft_accepted += int(np.minimum(m_r, al[s]).sum())
+                if take is not None:
+                    # device budget accounting must agree with the host's
+                    # dispatch-time prediction for stop-free rows
+                    assert len(toks) == take, (s, len(toks), take)
+                if self.active[s] is req:
+                    # per-row position clock: advances by exactly one per
+                    # emitted token, never for frozen rows
+                    assert pos[s] == self.positions[s] + len(toks), \
+                        (s, pos[s], self.positions[s], len(toks))
+                    self.positions[s] = int(pos[s])
+                    # trim the dispatch-time worst-case charge back to the
+                    # pages the clock actually reached (a no-op for budget
+                    # rows, a release for early-stopped / frozen rows)
+                    self._set_pages(s, self._pages_for(self.positions[s]))
+                    if take is None:
+                        self.remaining[s] = int(rem[s])
+                        if not alive[s]:
+                            if alens is not None:
+                                req.spec_accepted = int(acc[s])
+                                req.spec_proposed = int(prop[s])
+                            self.completed.append(req)
+                            self.active[s] = None
+                            self._free_pages(s)
 
     def run_until_drained(self, max_steps: int = 10_000) -> None:
         if self.stream:
@@ -1471,11 +1507,18 @@ def main() -> int:
     if mesh is not None:
         offl += (f" mesh={args.mesh}"
                  f" wire_bytes_per_shard={server.wire_bytes_per_shard}")
+    # every request is submitted up front, so its queue wait includes
+    # the compiles of the admissions ahead of it
+    admitted = [r for r in server.completed if r.first_token_at is not None]
+    wait_ms = np.median([r.admitted_at - r.arrival for r in admitted]) * 1e3
+    admit_ms = np.median([r.first_token_at - r.admitted_at
+                          for r in admitted]) * 1e3
     dev = jax.devices()[0]
     print(f"[serve] arch={server.cfg.arch_id} protocol={args.protocol} "
           f"mode={mode} sampling={'on' if sampled else 'greedy'} "
           f"requests={len(server.completed)} tokens={toks} "
           f"steps={server.steps} syncs/token={spt:.3f}{spec}{offl} "
+          f"queue_wait_p50={wait_ms:.1f}ms admit_p50={admit_ms:.1f}ms "
           f"({toks / dt:.1f} tok/s incl. compile; platform={dev.platform} "
           f"device_kind={dev.device_kind} devices={jax.device_count()})")
     return 0

@@ -309,6 +309,7 @@ def make_decode_segment(cfg: ArchConfig, seg_len: int, *,
     cannot flip greedy→stochastic mid-stream)."""
     model = get_model(cfg)
 
+    @jax.named_scope("decode_segment")
     def segment(params, cache, state: SlotState):
         def body(carry, _):
             toks, cache, pos, keys, remaining, alive = carry
@@ -319,12 +320,13 @@ def make_decode_segment(cfg: ArchConfig, seg_len: int, *,
                 nxt = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
                 hit_stop = jnp.zeros_like(alive)
             else:
-                both = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
-                keys, sub = both[:, 0], both[:, 1]
-                nxt = ops.sample_tokens(logits[:, -1], state.sampling, sub,
-                                        vocab=cfg.vocab)
-                nxt = jnp.where(alive, nxt, toks[:, 0])  # dead rows freeze
-                hit_stop = jnp.any(nxt[:, None] == state.stop, axis=-1)
+                with jax.named_scope("sampling_epilogue"):
+                    both = jax.vmap(lambda k: jax.random.split(k, 2))(keys)
+                    keys, sub = both[:, 0], both[:, 1]
+                    nxt = ops.sample_tokens(logits[:, -1], state.sampling,
+                                            sub, vocab=cfg.vocab)
+                    nxt = jnp.where(alive, nxt, toks[:, 0])  # dead rows freeze
+                    hit_stop = jnp.any(nxt[:, None] == state.stop, axis=-1)
             emitted = alive
             remaining = remaining - emitted.astype(jnp.int32)
             alive = alive & (remaining > 0) & ~hit_stop
@@ -428,6 +430,7 @@ def make_spec_decode_segment(cfg: ArchConfig, draft_cfg: ArchConfig,
     assert k >= 1, k
     t = k + 1
 
+    @jax.named_scope("decode_segment")
     def segment(params, draft_params, cache, draft_cache,
                 state: SlotState):
         b = state.positions.shape[0]
@@ -465,39 +468,43 @@ def make_spec_decode_segment(cfg: ArchConfig, draft_cfg: ArchConfig,
                 return (dcache_j, nxt[:, None]), \
                     (dtoks[:, 0], lg[:, -1], snap)
 
-            (dcache, last), (inputs, dlogits, dsnaps) = jax.lax.scan(
-                draft_body, (dcache, toks), jnp.arange(k))
-            # inputs (k, B): I_0 = current token, I_j = g_{j-1};
-            # dlogits[j] = p_j, the proposal distribution of g_j.
-            # The absorb step folds the final proposal g_{k-1} into the
-            # draft's own state (so a fully-accepted round leaves the
-            # draft cache consistent) — its logits feed nothing, so it
-            # skips the sampling epilogue entirely.
-            _, dcache = draft_model.decode_step(
-                draft_cfg, draft_params, dcache, last,
-                positions=pos + k, write_mask=alive)
-            absorb = {key: dcache[key][None] for key in dcache
-                      if key.startswith(("conv", "ssm"))}
-            dsnaps = {key: jnp.concatenate([dsnaps[key], absorb[key]])
-                      for key in dsnaps}                      # (T,L,B,…)
+            with jax.named_scope("spec_draft"):
+                (dcache, last), (inputs, dlogits, dsnaps) = jax.lax.scan(
+                    draft_body, (dcache, toks), jnp.arange(k))
+                # inputs (k, B): I_0 = current token, I_j = g_{j-1};
+                # dlogits[j] = p_j, the proposal distribution of g_j.
+                # The absorb step folds the final proposal g_{k-1} into
+                # the draft's own state (so a fully-accepted round leaves
+                # the draft cache consistent) — its logits feed nothing,
+                # so it skips the sampling epilogue entirely.
+                _, dcache = draft_model.decode_step(
+                    draft_cfg, draft_params, dcache, last,
+                    positions=pos + k, write_mask=alive)
+                absorb = {key: dcache[key][None] for key in dcache
+                          if key.startswith(("conv", "ssm"))}
+                dsnaps = {key: jnp.concatenate([dsnaps[key], absorb[key]])
+                          for key in dsnaps}                  # (T,L,B,…)
 
             # ---- 2. verify: one batched multi-position target forward
-            ver_tokens = jnp.concatenate([inputs.T, last], axis=1)  # (B,T)
-            tlogits, cache, tsnaps = model.decode_verify(
-                cfg, params, cache, ver_tokens, pos, write_mask=alive)
-            if plain:
-                # prefix-match-vs-argmax: bitwise the greedy rows of
-                # ops.verify_tokens, with none of the filtered-
-                # distribution or Gumbel machinery
-                out = jnp.argmax(tlogits.astype(jnp.float32),
-                                 axis=-1).astype(jnp.int32)   # (B,T)
-                match = (ver_tokens[:, 1:] == out[:, :k]).astype(jnp.int32)
-                alen = jnp.sum(jnp.cumprod(match, axis=-1), axis=-1)
-            else:
-                out, alen = ops.verify_tokens(
-                    tlogits, dlogits.transpose(1, 0, 2),
-                    ver_tokens[:, 1:], state.sampling, verify_keys,
-                    vocab=cfg.vocab)
+            with jax.named_scope("spec_verify"):
+                ver_tokens = jnp.concatenate([inputs.T, last],
+                                             axis=1)          # (B,T)
+                tlogits, cache, tsnaps = model.decode_verify(
+                    cfg, params, cache, ver_tokens, pos, write_mask=alive)
+                if plain:
+                    # prefix-match-vs-argmax: bitwise the greedy rows of
+                    # ops.verify_tokens, with none of the filtered-
+                    # distribution or Gumbel machinery
+                    out = jnp.argmax(tlogits.astype(jnp.float32),
+                                     axis=-1).astype(jnp.int32)   # (B,T)
+                    match = (ver_tokens[:, 1:]
+                             == out[:, :k]).astype(jnp.int32)
+                    alen = jnp.sum(jnp.cumprod(match, axis=-1), axis=-1)
+                else:
+                    out, alen = ops.verify_tokens(
+                        tlogits, dlogits.transpose(1, 0, 2),
+                        ver_tokens[:, 1:], state.sampling, verify_keys,
+                        vocab=cfg.vocab)
 
             # ---- 3. emit count: budget cap + first stop-set hit
             cand = jnp.minimum(alen + 1, remaining)
@@ -581,6 +588,7 @@ def make_prefill_into_cache(cfg: ArchConfig, *, from_enc_out: bool = False):
         from repro.models import encdec
 
         if from_enc_out:
+            @jax.named_scope("prefill")
             def prefill_ed_cached(params, cache, prompt, row, length,
                                   enc_out):
                 return encdec.prefill_into_cache(cfg, params, cache, prompt,
@@ -589,6 +597,7 @@ def make_prefill_into_cache(cfg: ArchConfig, *, from_enc_out: bool = False):
 
             return prefill_ed_cached
 
+        @jax.named_scope("prefill")
         def prefill_ed(params, cache, prompt, row, length, enc_embeds):
             return encdec.prefill_into_cache(cfg, params, cache, prompt,
                                              row, length, enc_embeds)
@@ -597,6 +606,7 @@ def make_prefill_into_cache(cfg: ArchConfig, *, from_enc_out: bool = False):
 
     from repro.models import transformer
 
+    @jax.named_scope("prefill")
     def prefill(params, cache, prompt, row, length):
         return transformer.prefill_into_cache(cfg, params, cache, prompt,
                                               row, length)
@@ -616,6 +626,7 @@ def make_resume_prefill(cfg: ArchConfig):
     if model.resume_prefill is None:
         return None
 
+    @jax.named_scope("prefill")
     def resume(params, cache, suffix, row, length, start):
         return model.resume_prefill(cfg, params, cache, suffix, row,
                                     length, start)
@@ -698,9 +709,11 @@ def make_slot_page_fns(cfg: ArchConfig):
     SSD state, enc-dec cross-KV + enc_pos)."""
     model = get_model(cfg)
 
+    @jax.named_scope("page_extract")
     def extract(cache, row, upto=None):
         return model.extract_slot(cfg, cache, row, upto)
 
+    @jax.named_scope("page_insert")
     def insert(cache, pages, row):
         return model.insert_slot(cfg, cache, pages, row)
 
