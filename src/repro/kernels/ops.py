@@ -206,8 +206,11 @@ def sample_tokens(logits, params: BatchedSampling, keys, *,
     sort over the first `ref.SAMPLE_HEAD` ranks, taken whenever every
     row's filters provably close inside the head (greedy, small top_k,
     or nucleus mass reached), with an in-graph `lax.cond` fallback to
-    the full-argsort reference otherwise — bitwise-identical samples
-    either way (asserted in tests/test_sampling.py).  There is still no
+    the full reference otherwise: one stable full-vocabulary sort that
+    carries the logits and the token-order probabilities into rank order
+    beside the token ids, so no (B, V) gather follows it —
+    bitwise-identical samples either way (asserted in
+    tests/test_sampling.py).  There is still no
     Pallas lowering — plain XLA on every backend, so sampling adds no
     kernel launches to the streamed segment (benchmarks/decode_stream.py
     records this accounting next to its asserted syncs/token figures)."""

@@ -229,7 +229,7 @@ def sample_tokens_reference(logits: jax.Array, temperature: jax.Array,
     Survivors are sampled via the Gumbel-argmax trick on the
     temperature-scaled logits: argmax(logits/T + G), G ~ Gumbel(0, 1)
     drawn per (row, token) from the row's key.  The draw happens in
-    descending-sorted space (one argsort total; the winner's RANK maps
+    descending-sorted space (one sort total; the winner's RANK maps
     back through the sort permutation) — same distribution, and for a
     fixed key the result is bitwise-deterministic — the property the
     streamed serve loop relies on for seg_len-invariant replay."""
@@ -279,7 +279,8 @@ def _sorted_keep(scaled: jax.Array, top_k: jax.Array, top_p: jax.Array,
                  min_p: jax.Array
                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """The top_k/top_p/min_p keep mask, computed in descending-sorted
-    space (stable argsort — ties broken by token id, deterministically).
+    space (one stable sort keyed on -scaled — ties broken by token id,
+    deterministically, exactly as `argsort(-scaled)` breaks them).
     Shared by sampling (`sample_tokens_reference`, which draws directly
     in sorted space) and verification (`filtered_log_probs`, which
     scatters the mask back to token space).  Returns (order (B,V) rank →
@@ -287,16 +288,19 @@ def _sorted_keep(scaled: jax.Array, top_k: jax.Array, top_p: jax.Array,
 
     Two structural choices exist so the `sample_tokens_capped` partial-
     sort fast path can be bitwise-identical over the head ranks:
-    probabilities are softmaxed in TOKEN order and gathered into rank
-    order (a gather preserves bits; the fast path computes the same
-    token-order softmax without sorting), and the cumulative nucleus
-    mass over ranks [0, SAMPLE_HEAD) comes from a cumsum of exactly that
-    head slice (a full-vocab cumsum may round differently)."""
+    probabilities are softmaxed in TOKEN order and carried into rank
+    order by the sort itself, as a value operand beside the logits and
+    the token ids (a sort permutes bits and negation is exact, so no
+    (B, V) gather is needed; the fast path computes the same token-order
+    softmax without sorting), and the cumulative nucleus mass over ranks
+    [0, SAMPLE_HEAD) comes from a cumsum of exactly that head slice (a
+    full-vocab cumsum may round differently)."""
     b, v = scaled.shape
-    order = jnp.argsort(-scaled, axis=-1)                     # (B,V)
-    sorted_logits = jnp.take_along_axis(scaled, order, axis=-1)
     probs_tok = jax.nn.softmax(scaled, axis=-1)               # token order
-    probs = jnp.take_along_axis(probs_tok, order, axis=-1)    # rank order
+    iota = jax.lax.broadcasted_iota(jnp.int32, (b, v), 1)
+    neg_sorted, probs, order = jax.lax.sort(                  # rank order
+        (-scaled, probs_tok, iota), dimension=1, num_keys=1, is_stable=True)
+    sorted_logits = -neg_sorted
     ranks = jnp.arange(v)[None, :]
     keep = jnp.ones((b, v), bool)
     keep &= jnp.where(top_k[:, None] > 0, ranks < top_k[:, None], True)
@@ -320,7 +324,7 @@ def sample_tokens_capped(logits: jax.Array, temperature: jax.Array,
                          ) -> jax.Array:
     """`sample_tokens_reference` with a partial-sort fast path.
 
-    The full reference pays an O(V log V) argsort per step; for serving
+    The full reference pays an O(V log V) sort per step; for serving
     params (greedy, modest top_k, nucleus top_p < 1) the winner's rank is
     almost surely within the first `head` ranks.  This entry computes the
     top-`head` ranks with `lax.top_k` (O(V)), checks per row that the
@@ -333,7 +337,8 @@ def sample_tokens_capped(logits: jax.Array, temperature: jax.Array,
     Bitwise-identical to `sample_tokens_reference` for every input:
       * `lax.top_k` ties break toward the lower index, exactly like the
         stable `argsort(-scaled)`, so head ranks/values match the sort.
-      * probabilities come from the same token-order softmax, gathered.
+      * probabilities come from the same token-order softmax, which the
+        reference's sort carries into rank order bit for bit.
       * the head's cumulative mass is the reference's own head cumsum
         (see `_sorted_keep`), so the keep mask matches over head ranks,
         and closure guarantees every tail rank is dropped by BOTH paths

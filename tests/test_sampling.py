@@ -27,7 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ops
+from repro.kernels import ops, ref
 
 try:
     from hypothesis import given, settings, strategies as st
@@ -239,6 +239,181 @@ def test_capped_fallback_branch_engages_and_matches():
     want = np.asarray(ref.sample_tokens_reference(
         lf, p.temperature, p.top_k, p.top_p, p.min_p, keys))
     np.testing.assert_array_equal(got, want)
+
+
+# ------------------------------------- one sort carries the sorted values
+
+V_PAD, VOCAB = 50_432, 50_280    # a padded vocab and its true width
+
+
+def _sorted_keep_gathers(scaled, top_k, top_p, min_p):
+    """The oracle for `ref._sorted_keep`: a stable argsort of -scaled,
+    then two (B, V) gathers into rank order — the formulation the
+    one-sort epilogue replaced, kept here so its bits stay the contract."""
+    b, v = scaled.shape
+    order = jnp.argsort(-scaled, axis=-1)
+    sorted_logits = jnp.take_along_axis(scaled, order, axis=-1)
+    probs_tok = jax.nn.softmax(scaled, axis=-1)
+    probs = jnp.take_along_axis(probs_tok, order, axis=-1)
+    ranks = jnp.arange(v)[None, :]
+    keep = jnp.ones((b, v), bool)
+    keep &= jnp.where(top_k[:, None] > 0, ranks < top_k[:, None], True)
+    head = min(ref.SAMPLE_HEAD, v)
+    cum_head = jnp.cumsum(probs[:, :head], axis=-1)
+    if v > head:
+        cum_tail = jnp.cumsum(probs, axis=-1)[:, head:]
+        cum = jnp.concatenate([cum_head, cum_tail], axis=-1)
+    else:
+        cum = cum_head
+    cum_before = cum - probs
+    keep &= (cum_before < top_p[:, None]) | (ranks == 0)
+    keep &= probs >= min_p[:, None] * probs[:, :1]
+    return order, sorted_logits, keep
+
+
+def _tied_logits(seed, shape):
+    """Random logits over the padded vocab with ties: each row's maximum
+    repeated at three ids, a block of columns copied onto a later block,
+    and +0.0 / -0.0 side by side."""
+    lf = np.random.default_rng(seed).standard_normal(shape).astype(np.float32)
+    lf[..., [5, 900, 40_000]] = lf.max(-1, keepdims=True)
+    lf[..., 2_000:2_400] = lf[..., 100:500]
+    lf[..., 3_000:3_050] = 0.0
+    lf[..., 3_050:3_100] = -0.0
+    return jnp.asarray(lf)
+
+
+EPILOGUE_MIXES = {
+    "greedy": [dict()],
+    "top_k": [dict(temperature=0.8, top_k=40)],
+    "top_p": [dict(temperature=0.7, top_p=0.9)],
+    "min_p": [dict(temperature=1.2, min_p=0.05)],
+    "mixed": [dict(), dict(temperature=0.8, top_k=40),
+              dict(temperature=0.7, top_p=0.9),
+              dict(temperature=1.2, min_p=0.05),
+              dict(temperature=0.9, top_k=100, top_p=0.95, min_p=0.01)],
+}
+
+
+def _mix_params(mix, b):
+    """Row i takes the mix's (i mod len)-th filter setting."""
+    rows = [EPILOGUE_MIXES[mix][i % len(EPILOGUE_MIXES[mix])]
+            for i in range(b)]
+    col = lambda name, default, dt: jnp.asarray(
+        [r.get(name, default) for r in rows], dt)
+    return ops.BatchedSampling(
+        temperature=col("temperature", 0.0, jnp.float32),
+        top_k=col("top_k", 0, jnp.int32),
+        top_p=col("top_p", 1.0, jnp.float32),
+        min_p=col("min_p", 0.0, jnp.float32))
+
+
+def _assert_bitwise(got, want):
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    np.testing.assert_array_equal(got.view(np.uint8), want.view(np.uint8))
+
+
+@pytest.mark.parametrize("mix", sorted(EPILOGUE_MIXES))
+@pytest.mark.parametrize("b", [1, 64])
+def test_sorted_keep_bitwise_matches_argsort_gathers(b, mix):
+    """The one stable sort that carries -logits and probabilities beside
+    the token ids gives bitwise the argsort-plus-gathers order, sorted
+    logits and keep mask: ties by id, +0.0 next to -0.0, -inf pad ids."""
+    p = _mix_params(mix, b)
+    scaled = ref._scaled_bounded_logits(_tied_logits(b, (b, V_PAD)),
+                                        p.temperature, VOCAB)
+    args = (scaled, p.top_k, p.top_p, p.min_p)
+    got = jax.jit(ref._sorted_keep)(*args)
+    want = jax.jit(_sorted_keep_gathers)(*args)
+    for g, w in zip(got, want):
+        _assert_bitwise(g, w)
+
+
+def _sample(sampling, b):
+    lf = _tied_logits(b + 1, (b, V_PAD))
+    return sampling(lf, _mix_params("mixed", b), keys_for(b, b), vocab=VOCAB)
+
+
+def _log_probs(filtered_log_probs, b):
+    p = _mix_params("mixed", b)
+    return filtered_log_probs(_tied_logits(b + 2, (b, V_PAD)),
+                              p.temperature, p.top_k, p.top_p, p.min_p,
+                              vocab=VOCAB)
+
+
+def _verify(verify, b):
+    k = 1
+    target = _tied_logits(b + 3, (b, k + 1, V_PAD))
+    draft = target[:, :k] + 0.5 * jnp.asarray(
+        np.random.default_rng(b).standard_normal((b, k, V_PAD)), jnp.float32)
+    tokens = jnp.argmax(draft[..., :VOCAB], axis=-1).astype(jnp.int32)
+    return verify(target, draft, tokens, _mix_params("mixed", b),
+                  keys_for(b, b), vocab=VOCAB)
+
+
+def _verify_reference(target, draft, tokens, p, keys, vocab):
+    return ref.verify_tokens_reference(target, draft, tokens, p.temperature,
+                                       p.top_k, p.top_p, p.min_p, keys, vocab)
+
+
+def _sample_reference(lf, p, keys, vocab):
+    return ref.sample_tokens_reference(lf, p.temperature, p.top_k, p.top_p,
+                                       p.min_p, keys, vocab)
+
+
+# entry: (drive, the served entry, its reference, run over the gathers)
+SORTED_KEEP_USERS = {
+    "sample_tokens": (_sample, ops.sample_tokens, _sample_reference),
+    "filtered_log_probs": (_log_probs, ref.filtered_log_probs,
+                           ref.filtered_log_probs),
+    "verify_tokens": (_verify, ops.verify_tokens, _verify_reference),
+}
+
+
+@pytest.mark.parametrize("entry", sorted(SORTED_KEEP_USERS))
+@pytest.mark.parametrize("b", [1, 64])
+def test_sorted_keep_users_match_gather_oracle(b, entry, monkeypatch):
+    """Every caller of `_sorted_keep` gives, for fixed keys, bitwise what
+    it gave over the argsort-plus-gathers formulation (mixed greedy /
+    top-k / top-p / min-p rows, tied logits, pad ids present)."""
+    drive, served, oracle = SORTED_KEEP_USERS[entry]
+    got = drive(served, b)
+    with monkeypatch.context() as m:
+        m.setattr(ref, "_sorted_keep", _sorted_keep_gathers)
+        want = drive(jax.jit(oracle, static_argnames=("vocab",)), b)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        _assert_bitwise(g, w)
+
+
+def _jaxpr_eqns(jaxpr):
+    """Every equation of a jaxpr, those of its nested jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (tuple, list)) else [param]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _jaxpr_eqns(inner)
+
+
+@pytest.mark.parametrize("entry", ["sample_tokens_reference",
+                                   "sample_tokens_capped"])
+def test_sampling_epilogue_one_sort_no_vocab_gather(entry):
+    """Structural guard: at chat's decode shape the sampling epilogue
+    holds exactly one sort and no gather with a vocabulary-wide output,
+    so the two (B, V) gathers into rank order cannot come back unseen."""
+    b = 64
+    f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+    jaxpr = jax.make_jaxpr(
+        lambda *a: getattr(ref, entry)(*a, vocab=VOCAB))(
+        f32(b, V_PAD), f32(b), jax.ShapeDtypeStruct((b,), jnp.int32),
+        f32(b), f32(b), jax.ShapeDtypeStruct((b, 2), jnp.uint32))
+    eqns = list(_jaxpr_eqns(jaxpr.jaxpr))
+    assert sum(e.primitive.name == "sort" for e in eqns) == 1
+    wide = [e for e in eqns if e.primitive.name == "gather"
+            and e.outvars[0].aval.shape[-1] == V_PAD]
+    assert not wide, wide
 
 
 # ------------------------------------------- hypothesis fuzz (optional)
