@@ -27,6 +27,7 @@ ARCH_IDS = (
     "mamba2_370m",
     "whisper_large_v3",
     "opt_2_7b",          # the paper's own LLM inference workload
+    "granite_4_0_h_small",
 )
 
 # Benchmark shapes (assignment): name -> (seq_len, global_batch, kind)

@@ -26,6 +26,12 @@ offload:
     partial (the current token's own (acc, m, l), merged in the epilogue
     so the cache can stay read-only during the layer scan).
 
+Softmax scale: every kernel here multiplies q by head_dim ** -0.5 (the
+prefill kernel's `scale` defaults to it).  A model with another scale
+(Granite's `attention_multiplier`) folds the ratio into q before the call
+(`transformer._qkv`, `ArchConfig.attn_scale`), so the kernels stay as
+they are and the product is the model's scale.
+
 VMEM budget per grid cell (bf16 inputs, f32 scratch):
   q (blk_q, hd) + k,v (blk_k, hd) + acc (blk_q, hd) + p (blk_q, blk_k).
 With blk_q = blk_k = 128 and hd = 128 that is ~0.3 MB — far below the

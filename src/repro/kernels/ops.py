@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro.kernels import flash_attention as _fa
+from repro.kernels import gmm as _gmm
 from repro.kernels import knn as _knn
 from repro.kernels import quant as _quant
 from repro.kernels import ref as _ref
@@ -255,6 +256,20 @@ def knn_distances(queries, db, *, blk_q: int = 128, blk_n: int = 128,
         return _knn.knn_distances(queries, db, blk_q=blk_q, blk_n=blk_n,
                                   interpret=interpret)
     return _ref.knn_distances_reference(queries, db)
+
+
+@_replicated_on_mesh
+@functools.partial(jax.jit, static_argnames=("tm", "interpret"))
+def expert_gmm(lhs, w, tile_group, n_live, w2=None, *, tm: int,
+               interpret: bool = False) -> jax.Array:
+    """Grouped matmul of the held experts over group-aligned rows
+    (`gmm.expert_gmm`; `w2` fuses the SwiGLU gate).  Nothing but the
+    kernel sits in this jit, so the trace's `%expert_gmm` ops are the
+    kernel's own time."""
+    if _on_tpu() or interpret:
+        return _gmm.expert_gmm(lhs, w, tile_group, n_live, w2, tm=tm,
+                               interpret=interpret)
+    return _ref.expert_gmm_reference(lhs, w, tile_group, n_live, w2, tm=tm)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "blk_q", "blk_n",

@@ -662,6 +662,31 @@ def dequantize_kv_pages(quants: jax.Array, scales: jax.Array) -> jax.Array:
 
 
 # --------------------------------------------------------------------------
+# Grouped expert matmul (mixture-of-experts FFN)
+# --------------------------------------------------------------------------
+
+def expert_gmm_reference(lhs: jax.Array, w: jax.Array,
+                         tile_group: jax.Array, n_live: jax.Array,
+                         w2: Optional[jax.Array] = None, *,
+                         tm: int) -> jax.Array:
+    """Oracle of `gmm.expert_gmm`: each `tm`-row tile of lhs times its
+    group's weights (silu(lhs·w) ⊙ lhs·w2 when w2 is given), zero on
+    tiles past n_live.  The weights are gathered per tile, so the work is
+    the live rows' only, not every group's."""
+    m, k = lhs.shape
+    tiles = lhs.reshape(m // tm, tm, k)
+    out = jnp.einsum("tmk,tkn->tmn", tiles, w[tile_group],
+                     preferred_element_type=jnp.float32)
+    if w2 is not None:
+        out = jax.nn.silu(out) * jnp.einsum(
+            "tmk,tkn->tmn", tiles, w2[tile_group],
+            preferred_element_type=jnp.float32)
+    live = jnp.arange(m // tm) < n_live[0]
+    out = jnp.where(live[:, None, None], out, 0.0)
+    return out.reshape(m, -1).astype(lhs.dtype)
+
+
+# --------------------------------------------------------------------------
 # KNN distances (VectorDB offload target)
 # --------------------------------------------------------------------------
 

@@ -66,7 +66,8 @@ def _leaf_spec(plan: PartitionPlan, cfg: ArchConfig, name: str,
 
     if name == "embed":                                  # (V, D)
         return P(ax(shape[0], tp), None)
-    if name in ("ln", "final_ln", "enc_final_ln", "dt_bias", "A_log", "D"):
+    if name in ("ln", "final_ln", "enc_final_ln", "dt_bias", "A_log", "D",
+                "conv_b", "norm"):                       # vectors
         return P(*([None] * nd))
     if name == "router":                                 # (nb, d, e)
         return P(*([None] * nd))
@@ -74,6 +75,8 @@ def _leaf_spec(plan: PartitionPlan, cfg: ArchConfig, name: str,
         return P(None, ax(shape[1], fs), ax(shape[2], tp))
     if name in ("wo", "out_proj"):                       # (nb, in, d)
         return P(None, ax(shape[1], tp), ax(shape[2], fs))
+    # a shared expert's w_gate / w_up / w_down are (nb, d, f) stacks, as
+    # a dense FFN's are
     if name in ("w_gate", "w_up"):
         if nd == 4:                                      # MoE (nb, e, d, f)
             if tp and _divisible(shape[1], mesh, tp):    # EP over experts

@@ -29,6 +29,12 @@ class ArchConfig:
     n_experts: int = 0             # 0 => dense FFN everywhere
     top_k: int = 0
     moe_every: int = 1             # MoE FFN on layers where l % moe_every == 0
+    # expert-parallel degree of the deployment: the router scores all
+    # n_experts, a chip holds n_experts // expert_shards of them (shard 0
+    # on one chip) and computes only their part of the routed sum
+    expert_shards: int = 1
+    shared_ff: int = 0             # width of a shared SwiGLU MLP beside
+                                   # the experts (0 => none)
 
     # --- attention pattern ---------------------------------------------------
     # Per-layer-in-block attention kind: 'full', 'local' (sliding window),
@@ -36,13 +42,21 @@ class ArchConfig:
     block_pattern: Tuple[str, ...] = ("full",)
     sliding_window: int = 4096
     rope_theta: float = 10_000.0
+    rope: bool = True              # False => NoPE (no rotary embedding)
     mrope: bool = False            # multimodal 3D rotary (qwen2-vl)
+    # softmax scale of q·k; 0 => head_dim ** -0.5.  Any other value is
+    # folded into q, so the kernels' own head_dim ** -0.5 yields it.
+    attn_scale: float = 0.0
 
     # --- SSM (mamba2 / jamba) -------------------------------------------------
     ssm_state: int = 128
     ssm_head_dim: int = 64         # P
     ssm_expand: int = 2            # d_inner = expand * d_model
     conv_width: int = 4
+    # the published Mamba-2 mixer: the causal conv (with bias) runs over
+    # x‖B‖C and a gated RMSNorm precedes out_proj.  False: conv over x
+    # only, no bias, y gated by silu(z) alone.
+    mamba_xbc: bool = False
 
     # --- serving ----------------------------------------------------------------
     # end-of-sequence token id: the default stop token serving callers put
@@ -63,6 +77,11 @@ class ArchConfig:
     frontend: str = "none"         # none | patch (vlm) | audio_conv (stub)
     tie_embeddings: bool = True
     norm_eps: float = 1e-6
+    # scalar multipliers (Granite): embedding output, every sublayer's
+    # residual branch, and the logits' divisor
+    embed_mult: float = 1.0
+    residual_mult: float = 1.0
+    logits_div: float = 1.0
 
     # --- numerics ---------------------------------------------------------------
     dtype: str = "bfloat16"
@@ -72,6 +91,7 @@ class ArchConfig:
 
     def __post_init__(self):
         assert self.n_layers % len(self.block_pattern) == 0, self.arch_id
+        assert self.n_experts % self.expert_shards == 0, self.arch_id
 
     @property
     def head_dim_(self) -> int:
@@ -104,8 +124,18 @@ class ArchConfig:
         return any(p in ("full", "local") for p in self.block_pattern)
 
     @property
+    def conv_dim(self) -> int:
+        """Channels of the causal conv (and of its cached window)."""
+        return self.d_inner + (2 * self.ssm_state if self.mamba_xbc else 0)
+
+    @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def held_experts(self) -> int:
+        """Experts whose weights one chip holds."""
+        return self.n_experts // self.expert_shards
 
     def attn_layers_per_block(self) -> int:
         return sum(1 for p in self.block_pattern if p in ("full", "local"))
@@ -122,11 +152,13 @@ class ArchConfig:
             elif kind == "mamba":
                 di, ns, nh = self.d_inner, self.ssm_state, self.n_ssm_heads
                 per_block += d * (2 * di + 2 * ns + nh) + di * d
+                per_block += self.conv_width * self.conv_dim
             per_block += 2 * d  # norms
             if kind != "none":
                 layer_idx = i
                 if self.is_moe and layer_idx % self.moe_every == 0:
                     per_block += self.n_experts * 3 * d * ff + d * self.n_experts
+                    per_block += 3 * d * self.shared_ff
                 else:
                     per_block += 3 * d * ff
         total += per_block * self.n_blocks

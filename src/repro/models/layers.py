@@ -4,7 +4,10 @@ drop-in replacements for the hot paths on TPU (selected via ops.py).
 
 Conventions:
   activations  x: (B, S, D)        bf16
-  attention    q: (B, S, H, hd), k/v: (B, S, KH, hd)
+  attention    q: (B, S, H, hd), k/v: (B, S, KH, hd); every attention
+               path scales q by 1/sqrt(hd) — a model with another softmax
+               scale arrives with the ratio already folded into q
+               (`transformer._qkv`)
   softmax / norm statistics in fp32.
 """
 from __future__ import annotations
@@ -29,6 +32,14 @@ def rms_norm(x: jax.Array, scale: jax.Array, eps: float = 1e-6) -> jax.Array:
     var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
     out = xf * jax.lax.rsqrt(var + eps) * (1.0 + scale.astype(jnp.float32))
     return out.astype(x.dtype)
+
+
+def rms_norm_gated(y: jax.Array, z: jax.Array, scale: jax.Array,
+                   eps: float = 1e-6) -> jax.Array:
+    """Mamba-2's gated RMSNorm: RMSNorm(y ⊙ z) over the last axis, z the
+    already-activated gate silu(z).  Statistics in fp32."""
+    g = y.astype(jnp.float32) * z.astype(jnp.float32)
+    return rms_norm(g, scale, eps).astype(y.dtype)
 
 
 # --------------------------------------------------------------------------
@@ -284,49 +295,73 @@ def gated_mlp(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
     return matmul(h, w_down)
 
 
-def moe_ffn(x: jax.Array, router: jax.Array, w_gate: jax.Array,
-            w_up: jax.Array, w_down: jax.Array, top_k: int,
-            capacity_factor: float = 1.25) -> jax.Array:
-    """Dropless-ish top-k MoE with capacity-bounded gather/scatter dispatch.
+def _tile_rows(t: int, top_k: int, n_experts: int) -> int:
+    """Rows of one grouped-matmul tile: the expected rows an expert gets
+    under uniform routing, rounded up to a power of two in [16, 128]
+    (16: bfloat16's sublane tile; 128: the MXU's edge)."""
+    want = -(-t * top_k // n_experts)
+    tm = 16
+    while tm < min(want, 128):
+        tm *= 2
+    return tm
 
-    The dispatch uses integer gathers (not one-hot einsums) so the lowered
-    HLO FLOP count reflects *active* expert compute - required for an honest
-    roofline (SS Roofline).  x: (T, D); router: (D, E); w_*: (E, D, F).
-    """
+
+def moe_held(x: jax.Array, router: jax.Array, w_gate: jax.Array,
+             w_up: jax.Array, w_down: jax.Array, top_k: int,
+             shard: int = 0) -> jax.Array:
+    """Dropless top-k MoE over the experts this chip holds.
+
+    The router scores all E experts (float32); each token takes its
+    top_k and a softmax over those k logits.  The chip holds experts
+    [shard·Eh, (shard+1)·Eh) (w_*: (Eh, D, F) / (Eh, F, D)) and computes
+    only the (token, expert) pairs that fall on them: no capacity, no
+    drop.  The pairs are laid out sorted by expert, each expert's run
+    padded to whole tiles, gate/up (fused SiLU·up) and down run as
+    grouped matmuls over those tiles (`ops.expert_gmm`), and each token
+    sums its held experts' outputs by gate weight.  With Eh = E the sum
+    is the whole routed layer; the parts of all shards add up to it.
+    x: (T, D) -> (T, D)."""
+    from repro.kernels import ops
     t, d = x.shape
     e = router.shape[1]
-    logits = (x.astype(jnp.float32) @ router.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)              # (T, E)
-    gate_vals, expert_ids = lax.top_k(probs, top_k)       # (T, K)
-    gate_vals = gate_vals / gate_vals.sum(-1, keepdims=True)
-    cap = int(math.ceil(t * top_k / e * capacity_factor))
-    cap = max(cap, 8)
-    # position of each (token, k) within its expert queue
-    flat_expert = expert_ids.reshape(-1)                  # (T*K,)
-    onehot = jax.nn.one_hot(flat_expert, e, dtype=jnp.int32)
-    pos = jnp.cumsum(onehot, axis=0) * onehot - 1         # (T*K, E)
-    pos_in_expert = pos.max(axis=-1)                      # (T*K,)
-    keep = pos_in_expert < cap
-    token_ids = jnp.repeat(jnp.arange(t), top_k)
-    # dispatch: slot (E, cap) -> token id (or T = sentinel row of zeros)
-    slot_token = jnp.full((e, cap), t, dtype=jnp.int32)
-    slot_token = slot_token.at[
-        jnp.where(keep, flat_expert, e - 1),
-        jnp.where(keep, pos_in_expert, cap - 1)].set(
-        jnp.where(keep, token_ids, slot_token[0, 0]), mode="drop")
-    x_pad = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)], axis=0)
-    xe = x_pad[slot_token]                                # (E, cap, D)
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, w_gate)) \
-        * jnp.einsum("ecd,edf->ecf", xe, w_up)
-    ye = jnp.einsum("ecf,efd->ecd", h, w_down)            # (E, cap, D)
-    # combine: scatter-add gated expert outputs back to tokens
-    gates_flat = jnp.where(keep, gate_vals.reshape(-1), 0.0)
-    slot_gate = jnp.zeros((e, cap), jnp.float32).at[
-        jnp.where(keep, flat_expert, e - 1),
-        jnp.where(keep, pos_in_expert, cap - 1)].set(gates_flat, mode="drop")
-    y = jnp.zeros((t + 1, d), jnp.float32).at[slot_token.reshape(-1)].add(
-        (ye * slot_gate[..., None]).reshape(-1, d), mode="drop")
-    return y[:t].astype(x.dtype)
+    eh = w_gate.shape[0]
+    with jax.named_scope("moe_route"):
+        # float32 as stated: the TPU's default would round the router to
+        # bfloat16 for one MXU pass and flip near-tied experts
+        logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                         precision=lax.Precision.HIGHEST)
+        top_logits, expert_ids = lax.top_k(logits, top_k)      # (T, K)
+        gates = jax.nn.softmax(top_logits, axis=-1)
+        local = expert_ids.reshape(-1) - shard * eh             # (T*K,)
+        held = (local >= 0) & (local < eh)
+        group = jnp.where(held, local, eh)                      # eh: not held
+        tm = _tile_rows(t, top_k, e)
+        cap = t * min(top_k, eh)                  # most pairs a chip can hold
+        m_pad = -(-cap // tm) * tm + eh * tm
+        onehot = jax.nn.one_hot(group, eh + 1, dtype=jnp.int32)
+        rank = ((jnp.cumsum(onehot, axis=0) - 1) * onehot).sum(axis=1)
+        sizes = onehot.sum(axis=0)[:eh]
+        padded = -(-sizes // tm) * tm
+        ends = jnp.cumsum(padded)
+        starts = ends - padded
+        dest = jnp.where(held, starts[jnp.minimum(group, eh - 1)] + rank,
+                         m_pad)                                 # (T*K,)
+        row_token = jnp.full((m_pad,), t, jnp.int32).at[dest].set(
+            jnp.arange(t * top_k, dtype=jnp.int32) // top_k, mode="drop")
+        n_live = ends[-1] // tm
+        tile_group = jnp.sum(
+            jnp.arange(0, m_pad, tm)[:, None] >= ends[None, :], axis=1)
+        tile_group = jnp.minimum(
+            tile_group, tile_group[jnp.maximum(n_live - 1, 0)])
+        tile_group = jnp.minimum(tile_group, eh - 1)
+    with jax.named_scope("expert_gmm"):
+        xs = jnp.concatenate([x, jnp.zeros((1, d), x.dtype)])[row_token]
+        live = n_live.reshape(1).astype(jnp.int32)
+        h = ops.expert_gmm(xs, w_gate, tile_group, live, w_up, tm=tm)
+        ys = ops.expert_gmm(h, w_down, tile_group, live, tm=tm)
+    ys = jnp.concatenate([ys, jnp.zeros((1, d), ys.dtype)])
+    yk = ys[dest.reshape(t, top_k)].astype(jnp.float32)        # (T, K, D)
+    return jnp.einsum("tkd,tk->td", yk, gates).astype(x.dtype)
 
 
 def moe_ffn_dist(x: jax.Array, router: jax.Array, w_gate: jax.Array,
@@ -334,9 +369,10 @@ def moe_ffn_dist(x: jax.Array, router: jax.Array, w_gate: jax.Array,
                  capacity_factor: float = 1.25) -> jax.Array:
     """Distribution-aware MoE (§Perf iteration G1, beyond-paper).
 
-    The plain `moe_ffn` under GSPMD routes the (T·K, E) rank cumsum and
-    the slot gathers across the token-sharded axis, which lowers to
-    per-layer all-gathers of x and rank tensors (measured: 74 s
+    The token-global dispatch (`moe_held`) under GSPMD routes the
+    (T·K, E) rank cumsum and the slot gathers across the token-sharded
+    axis, which lowers to per-layer all-gathers of x and rank tensors
+    (measured with the old capacity dispatch: 74 s
     collective / 110 s memory per step for granite-40e).  This variant
     forces *locality* with shard_map:
 
@@ -356,8 +392,13 @@ def moe_ffn_dist(x: jax.Array, router: jax.Array, w_gate: jax.Array,
     t, d = x.shape
     e = router.shape[1]
     if rules is None or rules.model_axis is None:
-        return moe_ffn(x, router, w_gate, w_up, w_down, top_k,
-                       capacity_factor)
+        # one device: all experts, or the share this chip holds
+        return moe_held(x, router, w_gate, w_up, w_down, top_k)
+    if w_gate.shape[0] != e:
+        raise NotImplementedError(
+            f"{w_gate.shape[0]} of {e} experts held: a mesh with a model "
+            "axis needs every expert until the expert exchange exists "
+            "(ROADMAP D8)")
     if rules.head_shard_attn:
         # bitwise serving (DESIGN.md §11): the capacity cumsum and expert
         # einsums couple ALL tokens, so a data-sharded batch lets GSPMD
@@ -369,8 +410,7 @@ def moe_ffn_dist(x: jax.Array, router: jax.Array, w_gate: jax.Array,
         from jax.sharding import PartitionSpec as _P
         repl = NamedSharding(rules.mesh, _P(None, None))
         x_r = lax.with_sharding_constraint(x, repl)
-        y = moe_ffn(x_r, router, w_gate, w_up, w_down, top_k,
-                    capacity_factor)
+        y = moe_held(x_r, router, w_gate, w_up, w_down, top_k)
         return lax.with_sharding_constraint(y, repl)
     mesh, maxis, baxes = rules.mesh, rules.model_axis, rules.batch_axes
     bsize = 1
@@ -383,8 +423,7 @@ def moe_ffn_dist(x: jax.Array, router: jax.Array, w_gate: jax.Array,
     # step's 8-token shards (measured: 12× collective regression on
     # jamba-398B decode; §Perf G1 scope note).
     if bsize == 0 or t % bsize or (t // bsize) < max(512, top_k):
-        return moe_ffn(x, router, w_gate, w_up, w_down, top_k,
-                       capacity_factor)
+        return moe_held(x, router, w_gate, w_up, w_down, top_k)
 
     e_pad = ((e + msize - 1) // msize) * msize
     if e_pad != e:
@@ -536,10 +575,11 @@ def ssd_decode_step(state: jax.Array, x: jax.Array, dt: jax.Array,
 
 
 def causal_conv1d(x: jax.Array, w: jax.Array,
-                  state: Optional[jax.Array] = None
+                  state: Optional[jax.Array] = None,
+                  bias: Optional[jax.Array] = None
                   ) -> Tuple[jax.Array, jax.Array]:
-    """Depthwise causal conv: x (b, s, c), w (width, c).  Returns (y, new
-    state = last width-1 inputs)."""
+    """Depthwise causal conv + SiLU: x (b, s, c), w (width, c), bias
+    (c,) or None.  Returns (y, new state = last width-1 inputs)."""
     width = w.shape[0]
     if state is None:
         state = jnp.zeros((x.shape[0], width - 1, x.shape[2]), x.dtype)
@@ -548,6 +588,8 @@ def causal_conv1d(x: jax.Array, w: jax.Array,
     windows = xp[:, idx]                                  # (b, s, w, c)
     y = jnp.einsum("bswc,wc->bsc", windows.astype(jnp.float32),
                    w.astype(jnp.float32))
+    if bias is not None:
+        y = y + bias.astype(jnp.float32)
     return jax.nn.silu(y).astype(x.dtype), xp[:, -(width - 1):]
 
 

@@ -43,25 +43,38 @@ def _init_attn(cfg: ArchConfig, key) -> Params:
     }
 
 
-def _init_ffn(cfg: ArchConfig, key, moe: bool) -> Params:
-    d, f = cfg.d_model, cfg.d_ff
+def _init_mlp(cfg: ArchConfig, key, f: int) -> Params:
+    d = cfg.d_model
     dt = jnp.dtype(cfg.dtype)
-    k1, k2, k3, k4 = jax.random.split(key, 4)
-    if moe:
-        e = cfg.n_experts
-        return {
-            "ln": jnp.zeros((d,), dt),
-            "router": (jax.random.normal(k4, (d, e)) * d ** -0.5).astype(jnp.float32),
-            "w_gate": (jax.random.normal(k1, (e, d, f)) * d ** -0.5).astype(dt),
-            "w_up": (jax.random.normal(k2, (e, d, f)) * d ** -0.5).astype(dt),
-            "w_down": (jax.random.normal(k3, (e, f, d)) * f ** -0.5).astype(dt),
-        }
+    k1, k2, k3 = jax.random.split(key, 3)
     return {
-        "ln": jnp.zeros((d,), dt),
         "w_gate": (jax.random.normal(k1, (d, f)) * d ** -0.5).astype(dt),
         "w_up": (jax.random.normal(k2, (d, f)) * d ** -0.5).astype(dt),
         "w_down": (jax.random.normal(k3, (f, d)) * f ** -0.5).astype(dt),
     }
+
+
+def _init_ffn(cfg: ArchConfig, key, moe: bool) -> Params:
+    """The FFN sublayer.  MoE: the router scores all n_experts; the
+    expert stacks hold this chip's `held_experts` (shard 0), and a
+    `shared` MLP runs beside them when `shared_ff` > 0."""
+    d, f = cfg.d_model, cfg.d_ff
+    dt = jnp.dtype(cfg.dtype)
+    k1, k2, k3, k4 = jax.random.split(key, 4)
+    if not moe:
+        return {"ln": jnp.zeros((d,), dt), **_init_mlp(cfg, k1, f)}
+    e, eh = cfg.n_experts, cfg.held_experts
+    out = {
+        "ln": jnp.zeros((d,), dt),
+        "router": (jax.random.normal(k4, (d, e)) * d ** -0.5).astype(jnp.float32),
+        "w_gate": (jax.random.normal(k1, (eh, d, f)) * d ** -0.5).astype(dt),
+        "w_up": (jax.random.normal(k2, (eh, d, f)) * d ** -0.5).astype(dt),
+        "w_down": (jax.random.normal(k3, (eh, f, d)) * f ** -0.5).astype(dt),
+    }
+    if cfg.shared_ff:
+        out["shared"] = _init_mlp(cfg, jax.random.fold_in(k4, 1),
+                                  cfg.shared_ff)
+    return out
 
 
 def _init_mamba(cfg: ArchConfig, key) -> Params:
@@ -69,7 +82,7 @@ def _init_mamba(cfg: ArchConfig, key) -> Params:
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 6)
     s = d ** -0.5
-    return {
+    p = {
         "ln": jnp.zeros((d,), dt),
         "w_z": (jax.random.normal(ks[0], (d, di)) * s).astype(dt),
         "w_x": (jax.random.normal(ks[1], (d, di)) * s).astype(dt),
@@ -79,10 +92,14 @@ def _init_mamba(cfg: ArchConfig, key) -> Params:
         "dt_bias": jnp.zeros((nh,), jnp.float32),
         "A_log": jnp.zeros((nh,), jnp.float32),       # A = -exp(A_log) = -1
         "D": jnp.ones((nh,), jnp.float32),
-        "conv_w": (jax.random.normal(ks[5], (cfg.conv_width, di))
+        "conv_w": (jax.random.normal(ks[5], (cfg.conv_width, cfg.conv_dim))
                    * cfg.conv_width ** -0.5).astype(dt),
         "out_proj": (jax.random.normal(ks[4], (di, d)) * di ** -0.5).astype(dt),
     }
+    if cfg.mamba_xbc:
+        p["conv_b"] = jnp.zeros((cfg.conv_dim,), dt)
+        p["norm"] = jnp.zeros((di,), dt)      # gated RMSNorm, offset from 1
+    return p
 
 
 def _is_moe_pos(cfg: ArchConfig, pos: int) -> bool:
@@ -142,6 +159,12 @@ def _qkv(cfg: ArchConfig, p: Params, x: jax.Array,
     q = matmul(hx, p["wq"]).reshape(b, s, h, hd)
     k = matmul(hx, p["wk"]).reshape(b, s, kh, hd)
     v = matmul(hx, p["wv"]).reshape(b, s, kh, hd)
+    if cfg.attn_scale:
+        # fold the configured softmax scale into q: every attention path
+        # (kernels included) multiplies q by hd ** -0.5, which this undoes
+        q = (q * (cfg.attn_scale * hd ** 0.5)).astype(q.dtype)
+    if not cfg.rope:
+        return q, k, v
     if cfg.mrope:
         pos3 = jnp.broadcast_to(positions[..., None], positions.shape + (3,))
         q = L.apply_mrope(q, pos3, cfg.rope_theta, _mrope_sections(hd))
@@ -156,6 +179,13 @@ def _mrope_sections(hd: int) -> Tuple[int, int, int]:
     half = hd // 2
     t = half - 2 * (half // 4)
     return (t, half // 4, half // 4)
+
+
+def _residual(cfg: ArchConfig, x: jax.Array, out: jax.Array) -> jax.Array:
+    """x + residual_mult · out, the one residual add of every sublayer."""
+    if cfg.residual_mult != 1.0:
+        out = out * jnp.asarray(cfg.residual_mult, out.dtype)
+    return x + out
 
 
 def attn_layer(cfg: ArchConfig, p: Params, x: jax.Array, kind: str,
@@ -175,28 +205,35 @@ def attn_layer(cfg: ArchConfig, p: Params, x: jax.Array, kind: str,
     # all-reduce over the sharded H*hd axis would re-associate the float
     # sum and break the bitwise serving contract (DESIGN.md §11)
     o = constrain(o, "batch")
-    return x + matmul(o, p["wo"])
+    return _residual(cfg, x, matmul(o, p["wo"]))
 
 
 def ffn_layer(cfg: ArchConfig, p: Params, x: jax.Array, moe: bool
               ) -> Tuple[jax.Array, jax.Array]:
     b, s, d = x.shape
     hx = L.rms_norm(x, p["ln"], cfg.norm_eps)
-    if moe:
+    if not moe:
+        y = L.gated_mlp(hx, p["w_gate"], p["w_up"], p["w_down"])
+        return _residual(cfg, x, y), jnp.zeros((), jnp.float32)
+    with jax.named_scope("moe"):
         flat = hx.reshape(b * s, d)
         y = L.moe_ffn_dist(flat, p["router"], p["w_gate"], p["w_up"],
-                           p["w_down"], cfg.top_k)
+                           p["w_down"], cfg.top_k).reshape(b, s, d)
+        if "shared" in p:
+            with jax.named_scope("shared_expert"):
+                sh = p["shared"]
+                y = y + L.gated_mlp(hx, sh["w_gate"], sh["w_up"],
+                                    sh["w_down"])
         aux = L.moe_aux_loss(flat, p["router"], cfg.top_k)
-        return x + y.reshape(b, s, d), aux
-    y = L.gated_mlp(hx, p["w_gate"], p["w_up"], p["w_down"])
-    return x + y, jnp.zeros((), jnp.float32)
+    return _residual(cfg, x, y), aux
 
 
 def _mamba_proj(cfg: ArchConfig, p: Params, x: jax.Array):
     """Shared input projections of the mamba sublayer: returns
     (z gate, conv INPUT, B, C, dt (softplus, f32), A) for the train /
     decode / prefill variants, which differ only in how they run the
-    conv + SSD recurrence."""
+    conv + SSD recurrence.  With `mamba_xbc` the conv input is x‖B‖C
+    and B, C come back None: they leave the conv (`_conv_split`)."""
     hx = L.rms_norm(x, p["ln"], cfg.norm_eps)
     z = jax.nn.silu(matmul(hx, p["w_z"]))
     xin = matmul(hx, p["w_x"])
@@ -204,19 +241,46 @@ def _mamba_proj(cfg: ArchConfig, p: Params, x: jax.Array):
     Cm = hx @ p["w_C"]
     dt = jax.nn.softplus((hx @ p["w_dt"]).astype(jnp.float32) + p["dt_bias"])
     A = -jnp.exp(p["A_log"])
+    if cfg.mamba_xbc:
+        return z, jnp.concatenate([xin, Bm, Cm], axis=-1), None, None, dt, A
     return z, xin, Bm, Cm, dt, A
+
+
+def _conv(p: Params, xin: jax.Array, state: Optional[jax.Array] = None):
+    return L.causal_conv1d(xin, p["conv_w"], state, p.get("conv_b"))
+
+
+def _conv_split(cfg: ArchConfig, xc: jax.Array, Bm, Cm):
+    """(x, B, C) of the SSD from the conv output: split x‖B‖C, or the
+    conv's x beside the projections' B and C."""
+    if not cfg.mamba_xbc:
+        return xc, Bm, Cm
+    di, n = cfg.d_inner, cfg.ssm_state
+    return xc[..., :di], xc[..., di:di + n], xc[..., di + n:]
+
+
+def _mamba_out(cfg: ArchConfig, p: Params, x: jax.Array, y: jax.Array,
+               xs: jax.Array, z: jax.Array) -> jax.Array:
+    """y + D·x, gated by z (through the gated RMSNorm with `mamba_xbc`),
+    out_proj, residual.  y, xs: (..., NH, P); z: (..., d_inner)."""
+    y = y + xs * p["D"][:, None].astype(xs.dtype)
+    y = y.reshape(z.shape)
+    if cfg.mamba_xbc:
+        y = L.rms_norm_gated(y, z, p["norm"], cfg.norm_eps).astype(x.dtype)
+    else:
+        y = (y * z).astype(x.dtype)
+    return _residual(cfg, x, matmul(y, p["out_proj"]))
 
 
 def mamba_layer(cfg: ArchConfig, p: Params, x: jax.Array) -> jax.Array:
     b, s, _ = x.shape
     nh, hp = cfg.n_ssm_heads, cfg.ssm_head_dim
     z, xin, Bm, Cm, dt, A = _mamba_proj(cfg, p, x)
-    xc, _ = L.causal_conv1d(xin, p["conv_w"])
-    y, _ = L.ssd_chunked(xc.reshape(b, s, nh, hp), dt, A, Bm, Cm)
-    y = y + (xc.reshape(b, s, nh, hp)
-             * p["D"][None, None, :, None].astype(xc.dtype))
-    y = (y.reshape(b, s, -1) * z).astype(x.dtype)
-    return x + matmul(y, p["out_proj"])
+    xc, _ = _conv(p, xin)
+    xc, Bm, Cm = _conv_split(cfg, xc, Bm, Cm)
+    xs = xc.reshape(b, s, nh, hp)
+    y, _ = L.ssd_chunked(xs, dt, A, Bm, Cm)
+    return _mamba_out(cfg, p, x, y, xs, z)
 
 
 # --------------------------------------------------------------------------
@@ -227,7 +291,24 @@ def _embed(cfg: ArchConfig, params: Params, batch: Dict[str, jax.Array]
            ) -> jax.Array:
     if "embeds" in batch:                        # vlm/audio stub frontends
         return batch["embeds"].astype(jnp.dtype(cfg.dtype))
-    return jnp.take(params["embed"], batch["tokens"], axis=0)
+    return embed_tokens(cfg, params, batch["tokens"])
+
+
+def embed_tokens(cfg: ArchConfig, params: Params, tokens: jax.Array
+                 ) -> jax.Array:
+    x = jnp.take(params["embed"], tokens, axis=0)
+    if cfg.embed_mult != 1.0:
+        x = x * jnp.asarray(cfg.embed_mult, x.dtype)
+    return x
+
+
+def head_logits(cfg: ArchConfig, params: Params, x: jax.Array
+                ) -> jax.Array:
+    """Logits of final-normed hidden states x (..., D) against the tied
+    embedding, divided by `logits_div`."""
+    if cfg.logits_div != 1.0:
+        x = x / jnp.asarray(cfg.logits_div, x.dtype)
+    return jnp.einsum("...d,vd->...v", x, params["embed"])
 
 
 def _block_fn(cfg: ArchConfig, x: jax.Array, block_params: Tuple[Params, ...],
@@ -274,6 +355,8 @@ def forward(cfg: ArchConfig, params: Params, batch: Dict[str, jax.Array],
 def loss_fn(cfg: ArchConfig, params: Params, batch: Dict[str, jax.Array]
             ) -> Tuple[jax.Array, Dict[str, jax.Array]]:
     x, aux = forward(cfg, params, batch)
+    if cfg.logits_div != 1.0:
+        x = x / jnp.asarray(cfg.logits_div, x.dtype)
     ce = L.xent_loss_chunked(x, params["embed"], batch["labels"],
                              vocab=cfg.vocab)
     loss = ce + AUX_LOSS_COEF * aux
@@ -284,8 +367,7 @@ def logits_fn(cfg: ArchConfig, params: Params, batch: Dict[str, jax.Array]
               ) -> jax.Array:
     """Full-sequence logits (prefill / evaluation path)."""
     x, _ = forward(cfg, params, batch, remat=False)
-    logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
-    return constrain(logits, "logits")
+    return constrain(head_logits(cfg, params, x), "logits")
 
 
 # --------------------------------------------------------------------------
@@ -356,7 +438,7 @@ def init_cache(cfg: ArchConfig, batch_size: int, max_seq: int,
                     (nb, b, kh, max_seq // ps), jnp.float32)
         elif kind == "mamba":
             cache[f"conv{pos}"] = jnp.zeros(
-                (nb, b, cfg.conv_width - 1, cfg.d_inner), dt)
+                (nb, b, cfg.conv_width - 1, cfg.conv_dim), dt)
             cache[f"ssm{pos}"] = jnp.zeros(
                 (nb, b, cfg.n_ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
                 jnp.float32)
@@ -431,8 +513,8 @@ def _decode_attn(cfg: ArchConfig, p: Params, x: jax.Array, kind: str,
     # attn_layer) — the shard_map above already returned o replicated,
     # this pins the layout so GSPMD never re-shards into a partial dot
     o = constrain(o.reshape(b, 1, cfg.n_heads * cfg.head_dim_), "batch")
-    return (x + matmul(o, p["wo"]), k_new.transpose(0, 2, 1, 3),
-            v_new.transpose(0, 2, 1, 3))
+    return (_residual(cfg, x, matmul(o, p["wo"])),
+            k_new.transpose(0, 2, 1, 3), v_new.transpose(0, 2, 1, 3))
 
 
 def _decode_mamba(cfg: ArchConfig, p: Params, x: jax.Array,
@@ -441,14 +523,13 @@ def _decode_mamba(cfg: ArchConfig, p: Params, x: jax.Array,
     b = x.shape[0]
     nh, hp = cfg.n_ssm_heads, cfg.ssm_head_dim
     z, xin, Bm, Cm, dt, A = _mamba_proj(cfg, p, x)
-    xc, conv_state = L.causal_conv1d(xin, p["conv_w"], conv_state)
-    y, ssm_state = L.ssd_decode_step(
-        ssm_state, xc[:, 0].reshape(b, nh, hp), dt[:, 0], A,
-        Bm[:, 0], Cm[:, 0])
-    y = y + (xc[:, 0].reshape(b, nh, hp)
-             * p["D"][None, :, None].astype(xc.dtype))
-    y = (y.reshape(b, 1, -1) * z).astype(x.dtype)
-    return x + matmul(y, p["out_proj"]), conv_state, ssm_state
+    xc, conv_state = _conv(p, xin, conv_state)
+    xc, Bm, Cm = _conv_split(cfg, xc, Bm, Cm)
+    xs = xc[:, 0].reshape(b, nh, hp)
+    y, ssm_state = L.ssd_decode_step(ssm_state, xs, dt[:, 0], A,
+                                     Bm[:, 0], Cm[:, 0])
+    return (_mamba_out(cfg, p, x, y[:, None], xs[:, None], z), conv_state,
+            ssm_state)
 
 
 def decode_step(cfg: ArchConfig, params: Params, cache: Dict[str, Any],
@@ -484,7 +565,7 @@ def decode_step(cfg: ArchConfig, params: Params, cache: Dict[str, Any],
     if tokens.ndim == 3:
         x = tokens.astype(jnp.dtype(cfg.dtype))
     else:
-        x = jnp.take(params["embed"], tokens, axis=0)
+        x = embed_tokens(cfg, params, tokens)
     pos = cache["pos"] if positions is None \
         else jnp.asarray(positions, jnp.int32)
     pages = cache.get("page_table")
@@ -522,7 +603,7 @@ def decode_step(cfg: ArchConfig, params: Params, cache: Dict[str, Any],
 
     x, ys = lax.scan(scan_body, x, (params["blocks"], xs))
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+    logits = head_logits(cfg, params, x)
 
     b = x.shape[0]
     out_cache: Dict[str, Any] = {"pos": cache["pos"] + 1}
@@ -649,7 +730,7 @@ def _verify_attn(cfg: ArchConfig, p: Params, x: jax.Array, kind: str,
     o = jnp.concatenate(outs, axis=1)                         # (B,T,H,hd)
     # bit-copy all-gather before wo (DESIGN.md §11; see attn_layer)
     o = constrain(o.reshape(b, t, cfg.n_heads * cfg.head_dim_), "batch")
-    return x + matmul(o, p["wo"]), k_new, v_new
+    return _residual(cfg, x, matmul(o, p["wo"])), k_new, v_new
 
 
 def _verify_mamba(cfg: ArchConfig, p: Params, x: jax.Array,
@@ -664,12 +745,13 @@ def _verify_mamba(cfg: ArchConfig, p: Params, x: jax.Array,
     rollback (DESIGN.md §7: rollback-as-gather): snapshot j is the state
     after absorbing chunk inputs 0..j.
 
-    x: (B, T, D).  Returns (x_out, conv_snaps (B, T, W-1, d_inner),
+    x: (B, T, D).  Returns (x_out, conv_snaps (B, T, W-1, conv_dim),
     ssm_snaps (B, T, NH, P, N) f32)."""
     b, t, _ = x.shape
     nh, hp, width = cfg.n_ssm_heads, cfg.ssm_head_dim, cfg.conv_width
     z, xin, Bm, Cm, dt, A = _mamba_proj(cfg, p, x)
-    xc, _ = L.causal_conv1d(xin, p["conv_w"], conv_state)
+    xc, _ = _conv(p, xin, conv_state)
+    xc, Bm, Cm = _conv_split(cfg, xc, Bm, Cm)
     # conv state after step j = the width-1 input window ending at j
     xp = jnp.concatenate([conv_state.astype(xin.dtype), xin], axis=1)
     conv_snaps = jnp.stack(
@@ -687,10 +769,8 @@ def _verify_mamba(cfg: ArchConfig, p: Params, x: jax.Array,
          Bm.transpose(1, 0, 2), Cm.transpose(1, 0, 2)))
     y = ys.transpose(1, 0, 2, 3)                              # (B,T,NH,P)
     ssm_snaps = states.transpose(1, 0, 2, 3, 4)               # (B,T,NH,P,N)
-    y = y + (xc.reshape(b, t, nh, hp)
-             * p["D"][None, None, :, None].astype(xc.dtype))
-    y = (y.reshape(b, t, -1) * z).astype(x.dtype)
-    return x + matmul(y, p["out_proj"]), conv_snaps, ssm_snaps
+    return (_mamba_out(cfg, p, x, y, xc.reshape(b, t, nh, hp), z),
+            conv_snaps, ssm_snaps)
 
 
 def decode_verify(cfg: ArchConfig, params: Params, cache: Dict[str, Any],
@@ -724,7 +804,7 @@ def decode_verify(cfg: ArchConfig, params: Params, cache: Dict[str, Any],
         rollback is a gather, never a recompute.
 
     Returns (logits (B, T, V), cache, snaps)."""
-    x = jnp.take(params["embed"], tokens, axis=0)             # (B,T,D)
+    x = embed_tokens(cfg, params, tokens)                     # (B,T,D)
     pos = jnp.asarray(positions, jnp.int32)
     b, t, _ = x.shape
     pages = cache.get("page_table")
@@ -760,7 +840,7 @@ def decode_verify(cfg: ArchConfig, params: Params, cache: Dict[str, Any],
 
     x, ys = lax.scan(scan_body, x, (params["blocks"], xs))
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
-    logits = jnp.einsum("bsd,vd->bsv", x, params["embed"])
+    logits = head_logits(cfg, params, x)
 
     out_cache: Dict[str, Any] = {"pos": cache["pos"] + t}
     if pages is not None:
@@ -1025,7 +1105,7 @@ def _prefill_mamba(cfg: ArchConfig, p: Params, x: jax.Array,
     left for prompts shorter than the conv width, matching the zero
     initial conv state of the per-token path).
 
-    Returns (x_out (B,S,D), conv_state (B,W-1,d_inner),
+    Returns (x_out (B,S,D), conv_state (B,W-1,conv_dim),
     ssm_state (B,NH,P,N) f32)."""
     from repro.kernels import ops
     b, s, _ = x.shape
@@ -1036,14 +1116,13 @@ def _prefill_mamba(cfg: ArchConfig, p: Params, x: jax.Array,
     conv_state = lax.dynamic_slice(
         pad, (0, jnp.asarray(length, jnp.int32), 0),
         (b, width - 1, xin.shape[-1]))
-    xc, _ = L.causal_conv1d(xin, p["conv_w"])
+    xc, _ = _conv(p, xin)
+    xc, Bm, Cm = _conv_split(cfg, xc, Bm, Cm)
     in_prompt = jnp.arange(s) < jnp.asarray(length, jnp.int32)
     dt = jnp.where(in_prompt[None, :, None], dt, 0.0)
-    y, ssm_state = ops.ssd_scan(xc.reshape(b, s, nh, hp), dt, A, Bm, Cm)
-    y = y + (xc.reshape(b, s, nh, hp)
-             * p["D"][None, None, :, None].astype(xc.dtype))
-    y = (y.reshape(b, s, -1) * z).astype(x.dtype)
-    return x + matmul(y, p["out_proj"]), conv_state, ssm_state
+    xs = xc.reshape(b, s, nh, hp)
+    y, ssm_state = ops.ssd_scan(xs, dt, A, Bm, Cm)
+    return _mamba_out(cfg, p, x, y, xs, z), conv_state, ssm_state
 
 
 def prefill_into_cache(cfg: ArchConfig, params: Params,
@@ -1068,7 +1147,7 @@ def prefill_into_cache(cfg: ArchConfig, params: Params,
     assert not cfg.enc_dec, "enc-dec prefill lives in encdec.prefill_into_cache"
     assert supports_prefill_into_cache(cfg), cfg.arch_id
     p_len = tokens.shape[0]
-    x = jnp.take(params["embed"], tokens[None], axis=0)   # (1,P,D)
+    x = embed_tokens(cfg, params, tokens[None])           # (1,P,D)
     positions = jnp.arange(p_len, dtype=jnp.int32)[None]
 
     def scan_body(x, block_params):
@@ -1080,7 +1159,7 @@ def prefill_into_cache(cfg: ArchConfig, params: Params,
                 window = cfg.sliding_window if kind == "local" else 0
                 o = ops.flash_attention(q, k, v, causal=True, window=window)
                 o = o.reshape(1, p_len, cfg.n_heads * cfg.head_dim_)
-                x = x + matmul(o, p["attn"]["wo"])
+                x = _residual(cfg, x, matmul(o, p["attn"]["wo"]))
                 states[f"k{pos_i}"] = k.transpose(0, 2, 1, 3)  # (1,KH,P,hd)
                 states[f"v{pos_i}"] = v.transpose(0, 2, 1, 3)
             elif kind == "mamba":
@@ -1094,7 +1173,7 @@ def prefill_into_cache(cfg: ArchConfig, params: Params,
     x, states = lax.scan(scan_body, x, params["blocks"])  # (L, 1, ...) each
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
     x_last = lax.dynamic_slice_in_dim(x, length - 1, 1, axis=1)  # (1,1,D)
-    logits = jnp.einsum("bsd,vd->bsv", x_last, params["embed"])[0, 0]
+    logits = head_logits(cfg, params, x_last)[0, 0]
 
     row = jnp.asarray(row, jnp.int32)
     out_cache = dict(cache)
@@ -1307,6 +1386,7 @@ def _resume_attention(cfg: ArchConfig, q: jax.Array, k: jax.Array,
     kh = k.shape[2]
     g = h // kh
     s = k_row.shape[2]
+    # the kernels' scale; a model's own scale is already folded into q
     qf = (q.astype(jnp.float32) * hd ** -0.5).reshape(b, t, kh, g, hd)
     gpos = start + jnp.arange(t, dtype=jnp.int32)          # global q positions
     slots = jnp.arange(s, dtype=jnp.int32)
@@ -1362,15 +1442,13 @@ def _resume_mamba(cfg: ArchConfig, p: Params, x: jax.Array,
     conv_state = lax.dynamic_slice(
         pad, (0, jnp.asarray(suffix_len, jnp.int32), 0),
         (b, width - 1, xin.shape[-1]))
-    xc, _ = L.causal_conv1d(xin, p["conv_w"], conv0)
+    xc, _ = _conv(p, xin, conv0)
+    xc, Bm, Cm = _conv_split(cfg, xc, Bm, Cm)
     in_suffix = jnp.arange(s) < jnp.asarray(suffix_len, jnp.int32)
     dt = jnp.where(in_suffix[None, :, None], dt, 0.0)
-    y, ssm_state = ops.ssd_scan(xc.reshape(b, s, nh, hp), dt, A, Bm, Cm,
-                                ssm0.astype(jnp.float32))
-    y = y + (xc.reshape(b, s, nh, hp)
-             * p["D"][None, None, :, None].astype(xc.dtype))
-    y = (y.reshape(b, s, -1) * z).astype(x.dtype)
-    return x + matmul(y, p["out_proj"]), conv_state, ssm_state
+    xs = xc.reshape(b, s, nh, hp)
+    y, ssm_state = ops.ssd_scan(xs, dt, A, Bm, Cm, ssm0.astype(jnp.float32))
+    return _mamba_out(cfg, p, x, y, xs, z), conv_state, ssm_state
 
 
 def resume_prefill_into_cache(cfg: ArchConfig, params: Params,
@@ -1406,7 +1484,7 @@ def resume_prefill_into_cache(cfg: ArchConfig, params: Params,
     row = jnp.asarray(row, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
     suffix_len = jnp.asarray(length, jnp.int32) - start
-    x = jnp.take(params["embed"], tokens[None], axis=0)   # (1,Ps,D)
+    x = embed_tokens(cfg, params, tokens[None])           # (1,Ps,D)
     positions = (start + jnp.arange(t_len, dtype=jnp.int32))[None]
     # the slot's restored pages ride the layer scan as READ-ONLY xs; on
     # a paged cache the self-KV leaves arrive as 6-dim page sets in
@@ -1437,7 +1515,8 @@ def resume_prefill_into_cache(cfg: ArchConfig, params: Params,
                                           + v_row.shape[4:])
                 o = _resume_attention(cfg, q, k, v, k_row, v_row,
                                       start, window)
-                x = x + matmul(o.reshape(1, t_len, -1), p["attn"]["wo"])
+                x = _residual(cfg, x, matmul(o.reshape(1, t_len, -1),
+                                             p["attn"]["wo"]))
                 states[f"k{pos_i}"] = k.transpose(0, 2, 1, 3)
                 states[f"v{pos_i}"] = v.transpose(0, 2, 1, 3)
             elif kind == "mamba":
@@ -1453,7 +1532,7 @@ def resume_prefill_into_cache(cfg: ArchConfig, params: Params,
     x, states = lax.scan(scan_body, x, (params["blocks"], row_cache))
     x = L.rms_norm(x, params["final_ln"], cfg.norm_eps)
     x_last = lax.dynamic_slice_in_dim(x, suffix_len - 1, 1, axis=1)
-    logits = jnp.einsum("bsd,vd->bsv", x_last, params["embed"])[0, 0]
+    logits = head_logits(cfg, params, x_last)[0, 0]
 
     out_cache = dict(cache)
     pt = cache.get("page_table")

@@ -74,6 +74,7 @@ def test_full_config_matches_assignment(arch_id):
         "mamba2_370m": (48, 1024, 16, 16, 0, 50280, 0, 0),
         "whisper_large_v3": (32, 1280, 20, 20, 5120, 51866, 0, 0),
         "opt_2_7b": None,
+        "granite_4_0_h_small": (40, 4096, 32, 8, 768, 100352, 72, 10),
     }[arch_id]
     if expected is None:
         return

@@ -60,7 +60,8 @@ def test_partition_specs_cover_every_leaf():
     from repro.models.registry import get_model
     from repro import sharding as sh
 
-    for arch in ("phi3_5_moe_42b", "jamba_1_5_large", "whisper_large_v3"):
+    for arch in ("phi3_5_moe_42b", "jamba_1_5_large", "whisper_large_v3",
+                 "granite_4_0_h_small"):
         cfg = get_config(arch)
         model = get_model(cfg)
         ab = model.abstract_params(cfg)
@@ -101,8 +102,9 @@ def test_production_mesh_cell_compiles():
 @pytest.mark.slow
 def test_moe_dist_matches_reference_on_mesh():
     """`moe_ffn_dist` (shard_map-local dispatch + padded EP, §Perf G1)
-    must match the single-device `moe_ffn` bit-for-bit on a real mesh,
-    for both EP-divisible and padded expert counts."""
+    must match the single-device dropless `moe_held` on a real mesh,
+    for both EP-divisible and padded expert counts (its capacity factor
+    of 8 drops nothing here)."""
     code = """
 import os
 os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'
@@ -118,7 +120,7 @@ for e in (6, 5):                     # divisible / padded
     wg = jax.random.normal(ks[2], (e, d, f)) * 0.1
     wu = jax.random.normal(ks[3], (e, d, f)) * 0.1
     wd = jax.random.normal(ks[4], (e, f, d)) * 0.1
-    ref = L.moe_ffn(x, router, wg, wu, wd, k, capacity_factor=8.0)
+    ref = L.moe_held(x, router, wg, wu, wd, k)
     with mesh, sh.use_rules(sh.ShardingRules(mesh)):
         got = jax.jit(lambda *a: L.moe_ffn_dist(
             *a, top_k=k, capacity_factor=8.0))(x, router, wg, wu, wd)

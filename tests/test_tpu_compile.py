@@ -12,8 +12,11 @@ not attached, and check that the program really holds the kernel
 Widths: starcoder2_3b decode (8 slots, 24 q heads over 2 KV heads,
 head_dim 128, its 16k context, 128-token pages), its 2048-token flash
 prefill and (3072 x 12288) FFN weight; mamba2_370m's SSD scan (32 heads
-x 64, state 128).  The kernel modules are called directly: `ops` would
-see the CPU backend and take the oracle branch.
+x 64, state 128); granite_4_0_h_small's grouped expert matmuls (18 held
+experts of 4096 x 768, decode at 64 slots x top-10 and a 4,096-token
+prefill), its 128-head SSD scan and its 32-over-8-head decode attention.
+The kernel modules are called directly: `ops` would see the CPU backend
+and take the oracle branch.
 """
 import os
 
@@ -23,13 +26,16 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import flash_attention as fa
-from repro.kernels import quant, ssd
+from repro.kernels import gmm, quant, ssd
 
 B, H, KH, HD, S, PAGE = 8, 24, 2, 128, 16384, 128
 N_PAGES = S // PAGE
 PREFILL = 2048
 SSM_H, SSM_P, SSM_N = 32, 64, 128
 D_MODEL, D_FF = 3072, 12288
+# granite_4_0_h_small
+G_D, G_FF, G_HELD, G_TOPK = 4096, 768, 18, 10
+G_H, G_KH, G_SSM_H = 32, 8, 128
 
 
 @pytest.fixture(scope="module")
@@ -108,6 +114,34 @@ def _quant(fmt, rows):
     return fn, [((rows, D_MODEL), jnp.bfloat16)] + parts
 
 
+def _gmm(rows, tm, fused):
+    """Group-aligned rows of `rows` tokens' held pairs at worst case (all
+    top-10 choices held) plus one padded tile per held expert."""
+    m = -(-rows * G_TOPK // tm) * tm + G_HELD * tm
+    k, n = (G_D, G_FF) if fused else (G_FF, G_D)
+
+    def fn(lhs, w, tiles, live, *w2):
+        return gmm.expert_gmm(lhs, w, tiles, live, *w2, tm=tm)
+
+    shapes = [((m, k), jnp.bfloat16), ((G_HELD, k, n), jnp.bfloat16),
+              ((m // tm,), jnp.int32), ((1,), jnp.int32)]
+    if fused:
+        shapes.append(((G_HELD, k, n), jnp.bfloat16))
+    return fn, shapes
+
+
+def _granite_decode():
+    def fn(q, k, v, pos, pages, *extra):
+        return fa.decode_attention_fused(q, k, v, pos, tuple(extra),
+                                         blk_c=PAGE, pages=pages)
+    s = 4096
+    return fn, [((B, 1, G_H, HD), jnp.bfloat16),
+                ((B, G_KH, s, HD), jnp.bfloat16),
+                ((B, G_KH, s, HD), jnp.bfloat16), ((B,), jnp.int32),
+                ((B, s // PAGE), jnp.int32), ((B, G_H, HD), jnp.float32),
+                ((B, G_H), jnp.float32), ((B, G_H), jnp.float32)]
+
+
 CASES = {
     "decode_fused.dense": lambda: _decode(),
     "decode_fused.dense_extra": lambda: _decode(extra=True),
@@ -127,6 +161,17 @@ CASES = {
     "quant_matmul.q4_k.decode": lambda: _quant("q4_k", B),
     "quant_matmul.q8_0.prefill": lambda: _quant("q8_0", PREFILL),
     "quant_matmul.q4_k.prefill": lambda: _quant("q4_k", PREFILL),
+    "expert_gmm.decode_gate_up": lambda: _gmm(64, 16, True),
+    "expert_gmm.decode_down": lambda: _gmm(64, 16, False),
+    "expert_gmm.prefill_gate_up": lambda: _gmm(4096, 128, True),
+    "expert_gmm.prefill_down": lambda: _gmm(4096, 128, False),
+    "ssd_scan.granite_prefill": lambda: (
+        ssd.ssd_scan,
+        [((1, PREFILL, G_SSM_H, SSM_P), jnp.bfloat16),
+         ((1, PREFILL, G_SSM_H), jnp.float32), ((G_SSM_H,), jnp.float32),
+         ((1, PREFILL, SSM_N), jnp.bfloat16),
+         ((1, PREFILL, SSM_N), jnp.bfloat16)]),
+    "decode_fused.granite_paged_extra": _granite_decode,
 }
 
 
