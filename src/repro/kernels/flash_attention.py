@@ -5,11 +5,13 @@ memory-side compute (Table I).  On TPU the analogue is running attention
 where the KV bytes live; these kernels are the compute hot-spot of that
 offload:
 
-  * `flash_attention_kernel` — causal / sliding-window GQA flash attention
-    with online softmax.  Grid (B, H, n_q, n_k): the KV axis is innermost
-    and accumulates partial-softmax statistics in VMEM scratch, exactly
-    the (acc, m, l) statistic stream that the back-streaming protocol
-    ships between shards.
+  * `flash_attention` — causal / sliding-window GQA flash attention
+    (prefill) with online softmax.  Grid (B, KH, n_q, n_k): the KV axis
+    is innermost and accumulates partial-softmax statistics in VMEM
+    scratch, exactly the (acc, m, l) statistic stream that the
+    back-streaming protocol ships between shards.  Only (q block, kv
+    block) pairs holding a live (query, key) pair run; a grid cell holds
+    a whole GQA group's query rows.
   * `decode_partial_kernel` — single-token attention over one KV chunk,
     emitting the raw (acc, m, l) partials.  This is the producer-side
     task of `repro.core.backstream.decode_attention_combined`.
@@ -26,17 +28,23 @@ offload:
     partial (the current token's own (acc, m, l), merged in the epilogue
     so the cache can stay read-only during the layer scan).
 
-Softmax scale: every kernel here multiplies q by head_dim ** -0.5 (the
-prefill kernel's `scale` defaults to it).  A model with another scale
+Softmax scale: every kernel here scales by head_dim ** -0.5 (the
+decode kernels multiply q, the prefill kernel its float32 scores; its
+`scale` defaults to it).  A model with another scale
 (Granite's `attention_multiplier`) folds the ratio into q before the call
 (`transformer._qkv`, `ArchConfig.attn_scale`), so the kernels stay as
 they are and the product is the model's scale.
 
-VMEM budget per grid cell (bf16 inputs, f32 scratch):
-  q (blk_q, hd) + k,v (blk_k, hd) + acc (blk_q, hd) + p (blk_q, blk_k).
-With blk_q = blk_k = 128 and hd = 128 that is ~0.3 MB — far below the
-~16 MB VMEM of a v5e core, leaving room for XLA's double buffering.
-All matmul dims are multiples of 128 => MXU-aligned.
+Prefill VMEM budget per grid cell (bf16 inputs, f32 scratch), with
+rows = group · blk_q <= FLASH_ROWS (2,048) and blk_k <= FLASH_BLK_K (512):
+  q (rows, hd) ×2 buffers + k, v (blk_k, hd) ×2 each + acc (rows, hd)
+  + m, l (rows, 1) + the score, probability and mask temporaries
+  (rows, blk_k) each.
+At rows 2,048, blk_k 512 and hd 128 that is ~1.5 MB of blocks and
+scratch plus ~4 MB per temporary, inside the 64 MB scoped limit the
+call sets (FLASH_VMEM_LIMIT, as `gmm.py` does).  starcoder2_3b's
+group of 12 takes blk_q 128 (1,536 rows), granite_4_0_h_small's group
+of 4 blk_q 512.  Matmul dims are multiples of 128 => MXU-aligned.
 """
 from __future__ import annotations
 
@@ -45,6 +53,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -58,11 +67,82 @@ NEG_INF = -1e30
 # Prefill flash attention
 # --------------------------------------------------------------------------
 
-def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
-                  scale: float, blk_q: int, blk_k: int, causal: bool,
-                  window: int, n_k: int):
+# Query rows one grid cell holds (a whole GQA group's heads times blk_q
+# positions), KV positions one K/V tile holds, and the scoped VMEM the
+# kernel may use: a (2048, 512) f32 score tile is 4 MB, and the score,
+# probability and mask temporaries of one step stay well inside.
+FLASH_ROWS = 2048
+FLASH_BLK_K = 512
+FLASH_VMEM_LIMIT = 64 * 1024 * 1024
+
+
+def _tile(s: int, cap: int) -> int:
+    """`s` itself when it fits under `cap`, else the largest power of two
+    (>= 8) not above `cap` that divides `s`."""
+    if s <= cap:
+        return s
+    t = 8
+    while t * 2 <= cap and s % (t * 2) == 0:
+        t *= 2
+    assert s % t == 0, (s, cap)
+    return t
+
+
+def flash_tiles(s: int, group: int) -> Tuple[int, int]:
+    """(blk_q, blk_k) of the prefill kernel for `s` tokens and `group`
+    query heads per KV head: a grid cell multiplies a (group · blk_q, hd)
+    block of the group's query rows against one (blk_k, hd) K/V tile."""
+    return _tile(s, max(FLASH_ROWS // group, 8)), _tile(s, FLASH_BLK_K)
+
+
+def _block_live(q0, q1, k0, k1, length, causal: bool, window: int):
+    """Whether the (query block [q0, q1), key block [k0, k1)) pair holds
+    at least one live (query, key) pair: both below `length`, the key not
+    after the query (causal) and within `window` of it.  Plain integer
+    and comparison arithmetic, so the kernel evaluates it on scalars and
+    `flash_live_blocks` on NumPy grids of block origins."""
+    live = (q0 < length) & (k0 < length)
+    if causal:
+        live = live & (k0 < q1)
+    if window > 0:
+        live = live & (q0 - k1 + 1 < window)
+    return live
+
+
+def _block_full(q0, q1, k0, k1, length, causal: bool, window: int):
+    """Whether no (query, key) pair of the block is masked, so the block
+    can skip building the mask.  Query rows at or past `length` are junk
+    and mask only by key, like every other row."""
+    full = k1 <= length
+    if causal:
+        full = full & (k1 - 1 <= q0)
+    if window > 0:
+        full = full & (q1 - 1 - k0 < window)
+    return full
+
+
+def flash_live_blocks(length: int, s: int, blk_q: int, blk_k: int,
+                      causal: bool = True, window: int = 0
+                      ) -> Tuple[int, int]:
+    """(live, total) (query block, key block) pairs of one head group's
+    prefill grid over `s` tokens of which the first `length` are the
+    prompt: the pairs the kernel computes, by the kernel's own
+    predicate, against the pairs its grid visits."""
+    q0 = np.arange(s // blk_q)[:, None] * blk_q
+    k0 = np.arange(s // blk_k)[None, :] * blk_k
+    live = _block_live(q0, q0 + blk_q, k0, k0 + blk_k, length, causal,
+                       window)
+    return int(live.sum()), live.size
+
+
+def _flash_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                  l_ref, *, scale: float, blk_q: int, blk_k: int,
+                  causal: bool, window: int):
     i = pl.program_id(2)          # q block
     j = pl.program_id(3)          # kv block (innermost, accumulating)
+    length = len_ref[0]
+    q0, k0 = i * blk_q, j * blk_k
+    q1, k1 = q0 + blk_q, k0 + blk_k
 
     @pl.when(j == 0)
     def _init():
@@ -70,88 +150,140 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    q = q_ref[0, 0].astype(jnp.float32) * scale            # (blk_q, hd)
-    k = k_ref[0, 0].astype(jnp.float32)                    # (blk_k, hd)
-    v = v_ref[0, 0].astype(jnp.float32)
+    def step(masked: bool):
+        # q·kᵀ on the operands as they arrive (bf16 on the chip: the
+        # products are exact in the float32 accumulator); p·v in float32
+        s = jax.lax.dot_general(q_ref[0, 0, 0], k_ref[0, 0],
+                                (((1,), (1,)), ((), ())),
+                                preferred_element_type=jnp.float32) * scale
+        if masked:
+            # a column of query positions against a row of key positions
+            qpos = q0 + lax.rem(
+                lax.broadcasted_iota(jnp.int32, (s.shape[0], 1), 0), blk_q)
+            kpos = k0 + lax.broadcasted_iota(jnp.int32, (1, blk_k), 1)
+            mask = kpos < length
+            if causal:
+                mask &= kpos <= qpos
+            if window > 0:
+                mask &= kpos > qpos - window
+            s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[...]                                   # (rows, 1)
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        if masked:
+            p = jnp.where(mask, p, 0.0)
+        alpha = jnp.exp(m_prev - m_new)
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot(
+            p, v_ref[0, 0].astype(jnp.float32),
+            preferred_element_type=jnp.float32)
+        m_ref[...] = m_new
 
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
+    live = _block_live(q0, q1, k0, k1, length, causal, window)
+    full = _block_full(q0, q1, k0, k1, length, causal, window)
 
-    qpos = i * blk_q + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 0)
-    kpos = j * blk_k + lax.broadcasted_iota(jnp.int32, (blk_q, blk_k), 1)
-    mask = jnp.ones((blk_q, blk_k), jnp.bool_)
-    if causal:
-        mask &= kpos <= qpos
-    if window > 0:
-        mask &= kpos > qpos - window
-    s = jnp.where(mask, s, NEG_INF)
+    @pl.when(live & full)
+    def _unmasked():
+        step(masked=False)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1))
-    p = jnp.exp(s - m_new[:, None])
-    p = jnp.where(mask, p, 0.0)
-    alpha = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * alpha + jnp.sum(p, axis=-1)
-    acc_ref[...] = (acc_ref[...] * alpha[:, None]
-                    + jax.lax.dot(p, v, preferred_element_type=jnp.float32))
-    m_ref[...] = m_new
+    @pl.when(live & jnp.logical_not(full))
+    def _masked():
+        step(masked=True)
 
-    @pl.when(j == n_k - 1)
+    @pl.when(j == pl.num_programs(3) - 1)
     def _finish():
-        l = l_ref[...]
-        o_ref[0, 0] = (acc_ref[...]
-                       / jnp.maximum(l, 1e-20)[:, None]).astype(o_ref.dtype)
+        o_ref[0, 0, 0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-20)
+                          ).astype(o_ref.dtype)
 
 
-def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
+def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array,
+                    length: Optional[jax.Array] = None, *,
                     causal: bool = True, window: int = 0,
                     scale: Optional[float] = None,
-                    blk_q: int = 128, blk_k: int = 128,
                     interpret: bool = False) -> jax.Array:
-    """q: (B,S,H,hd); k,v: (B,S,KH,hd) -> (B,S,H,hd).  GQA supported."""
+    """q: (B,S,H,hd); k,v: (B,S,KH,hd) -> (B,S,H,hd).  GQA supported.
+
+    `length` (int32 scalar, may be traced): the first `length` tokens are
+    the prompt, the rest padding; None means all S.  Query rows at or
+    past it are junk (finite; zero where no block of theirs is live),
+    and keys at or past it are seen by no query.  Rows below it are the
+    unpadded attention's.
+
+    Grid (B, KH, n_q, n_k), KV axis innermost and accumulating the
+    (acc, m, l) softmax statistics in VMEM scratch.  A cell holds one
+    KV head's whole GQA group: the group's query rows for blk_q
+    positions form one (group · blk_q, hd) block against one (blk_k, hd)
+    K/V tile (`flash_tiles`), so each K/V tile is read once per group.
+    A (q block, kv block) pair runs only if `_block_live` holds: not
+    wholly above the causal diagonal, outside the window, or past
+    `length` on either axis.  The live kv blocks of a q block are one
+    contiguous run [lo, hi]; the K/V index maps clamp to it (and the q
+    map to the last live q block), so a skipped step repeats the block
+    index and issues no copy.  Only pairs that `_block_full` does not
+    clear build the mask.  The softmax scale multiplies the float32
+    scores."""
     b, s, h, hd = q.shape
     kh = k.shape[2]
     assert h % kh == 0
     group = h // kh
-    blk_q = min(blk_q, s)
-    blk_k = min(blk_k, s)
-    assert s % blk_q == 0 and s % blk_k == 0, (s, blk_q, blk_k)
+    blk_q, blk_k = flash_tiles(s, group)
     n_q, n_k = s // blk_q, s // blk_k
+    rows = group * blk_q
     scale = scale if scale is not None else hd ** -0.5
+    if length is None:
+        length = s
+    length = jnp.reshape(jnp.asarray(length, jnp.int32), (1,))
 
-    # (B,H,S,hd) layout so the (q block, kv block) tiles are contiguous.
-    qt = q.transpose(0, 2, 1, 3)
-    kt = k.transpose(0, 2, 1, 3)
+    # q rows of one (KV head, q block) cell are contiguous: head-major
+    # within the group, position-minor
+    qt = (q.reshape(b, n_q, blk_q, kh, group, hd)
+           .transpose(0, 3, 1, 4, 2, 5).reshape(b, kh, n_q, rows, hd))
+    kt = k.transpose(0, 2, 1, 3)                          # (B,KH,S,hd)
     vt = v.transpose(0, 2, 1, 3)
+
+    def q_map(b_, h_, i, j, len_ref):
+        return b_, h_, jnp.minimum(i, (len_ref[0] - 1) // blk_q), 0, 0
+
+    def kv_map(b_, h_, i, j, len_ref):
+        last = jnp.minimum((i + 1) * blk_q, len_ref[0]) if causal \
+            else len_ref[0]
+        hi = (last - 1) // blk_k
+        lo = jnp.maximum((i * blk_q - window + 1) // blk_k, 0) \
+            if window > 0 else 0
+        return b_, h_, jnp.minimum(jnp.maximum(j, lo), hi), 0
+
+    def o_map(b_, h_, i, j, len_ref):
+        return b_, h_, i, 0, 0
 
     kernel = functools.partial(
         _flash_kernel, scale=scale, blk_q=blk_q, blk_k=blk_k,
-        causal=causal, window=window, n_k=n_k)
+        causal=causal, window=window)
 
     out = pl.pallas_call(
         kernel,
-        grid=(b, h, n_q, n_k),
-        in_specs=[
-            pl.BlockSpec((1, 1, blk_q, hd), lambda b_, h_, i, j: (b_, h_, i, 0)),
-            pl.BlockSpec((1, 1, blk_k, hd),
-                         lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
-            pl.BlockSpec((1, 1, blk_k, hd),
-                         lambda b_, h_, i, j: (b_, h_ // group, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, blk_q, hd),
-                               lambda b_, h_, i, j: (b_, h_, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, h, s, hd), q.dtype),
-        scratch_shapes=[
-            pltpu.VMEM((blk_q, hd), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
-            pltpu.VMEM((blk_q,), jnp.float32),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, kh, n_q, n_k),
+            in_specs=[
+                pl.BlockSpec((1, 1, 1, rows, hd), q_map),
+                pl.BlockSpec((1, 1, blk_k, hd), kv_map),
+                pl.BlockSpec((1, 1, blk_k, hd), kv_map),
+            ],
+            out_specs=pl.BlockSpec((1, 1, 1, rows, hd), o_map),
+            scratch_shapes=[
+                pltpu.VMEM((rows, hd), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+                pltpu.VMEM((rows, 1), jnp.float32),
+            ]),
+        out_shape=jax.ShapeDtypeStruct((b, kh, n_q, rows, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+                                 "arbitrary"),
+            vmem_limit_bytes=FLASH_VMEM_LIMIT),
         interpret=interpret,
-    )(qt, kt, vt)
-    return out.transpose(0, 2, 1, 3)
+    )(length, qt, kt, vt)
+    return (out.reshape(b, kh, n_q, group, blk_q, hd)
+               .transpose(0, 2, 4, 1, 3, 5).reshape(b, s, h, hd))
 
 
 # --------------------------------------------------------------------------

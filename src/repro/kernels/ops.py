@@ -54,16 +54,16 @@ def _replicated_on_mesh(fn):
 
 
 @_replicated_on_mesh
-@functools.partial(jax.jit, static_argnames=(
-    "causal", "window", "blk_q", "blk_k", "interpret"))
-def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    blk_q: int = 128, blk_k: int = 128,
-                    interpret: bool = False) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("causal", "window", "interpret"))
+def flash_attention(q, k, v, length=None, *, causal: bool = True,
+                    window: int = 0, interpret: bool = False) -> jax.Array:
+    """Prefill attention.  `length`: optional int32 scalar, the prompt's
+    true length within the padded S (None: all of it); rows below it are
+    exact, rows at or past it junk but finite."""
     if _on_tpu() or interpret:
-        return _fa.flash_attention(q, k, v, causal=causal, window=window,
-                                   blk_q=blk_q, blk_k=blk_k,
-                                   interpret=interpret)
-    return _ref.mha_reference(q, k, v, causal=causal, window=window)
+        return _fa.flash_attention(q, k, v, length, causal=causal,
+                                   window=window, interpret=interpret)
+    return _ref.mha_reference(q, k, v, length, causal=causal, window=window)
 
 
 @functools.partial(jax.jit, static_argnames=("blk_c", "interpret"))

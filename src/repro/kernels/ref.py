@@ -16,11 +16,16 @@ import jax.numpy as jnp
 # Flash attention (the paper's LLM-inference offload target, Table I)
 # --------------------------------------------------------------------------
 
-def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
+def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array,
+                  length: Optional[jax.Array] = None, *,
                   causal: bool = True, window: int = 0,
                   scale: Optional[float] = None) -> jax.Array:
     """Multi-head attention with GQA.  q: (B,S,H,hd); k,v: (B,S,KH,hd).
-    window > 0 => sliding-window causal attention.  Returns (B,S,H,hd)."""
+    window > 0 => sliding-window causal attention.  `length` (optional
+    int32 scalar): keys at or past it are padding, seen by no query
+    below it (under causal masking no such row saw them anyway); rows at
+    or past it are padding too and keep their unmasked keys, so they
+    stay finite.  Returns (B,S,H,hd)."""
     b, s, h, hd = q.shape
     kh = k.shape[2]
     assert h % kh == 0
@@ -37,6 +42,8 @@ def mha_reference(q: jax.Array, k: jax.Array, v: jax.Array, *,
         mask &= kpos <= qpos
     if window > 0:
         mask &= kpos > qpos - window
+    if length is not None:
+        mask &= (kpos < length) | (qpos >= length)
     logits = jnp.where(mask[None, None], logits, -jnp.inf)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, vf)

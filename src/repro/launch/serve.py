@@ -106,6 +106,7 @@ from repro.core import ring as ring_lib
 from repro.core.backstream import (HostTier, OffloadConfig, OffloadProtocol,
                                    PrefixCache, stream_offload_to_device,
                                    stream_offload_to_host, use_offload)
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops
 from repro.launch import steps as steps_lib
 from repro.launch.compile_cache import use_compile_cache
@@ -216,6 +217,21 @@ def _prefill_bucket(n: int, cap: int) -> int:
     while p < n:
         p *= 2
     return min(p, cap)
+
+
+def _flash_live_share(cfg, plen: int, bucket: int) -> Optional[float]:
+    """Share of the flash prefill kernel's (q block, kv block) grid that a
+    `plen`-token prompt padded to `bucket` computes, over the model's
+    attention layers, by the kernel's own predicate
+    (`flash_attention.flash_live_blocks`); None without attention."""
+    windows = [cfg.sliding_window if kind == "local" else 0
+               for kind in cfg.block_pattern if kind in ("full", "local")]
+    if not windows:
+        return None
+    tiles = fa.flash_tiles(bucket, cfg.n_heads // cfg.n_kv_heads)
+    counts = [fa.flash_live_blocks(plen, bucket, *tiles, True, w)
+              for w in windows]
+    return sum(c[0] for c in counts) / sum(c[1] for c in counts)
 
 
 class BatchedServer:
@@ -933,8 +949,12 @@ class BatchedServer:
             # row's final position; keep them off the valid prefix
             assert plen + max_new + self.spec_k <= self.max_seq, \
                 (plen, max_new, self.spec_k, self.max_seq)
-        with TraceAnnotation("serve.admit", rid=req.rid, prompt_len=plen,
-                             bucket=_prefill_bucket(plen, self.max_seq)):
+        bucket = _prefill_bucket(plen, self.max_seq)
+        span = dict(rid=req.rid, prompt_len=plen, bucket=bucket)
+        share = _flash_live_share(self.cfg, plen, bucket)
+        if share is not None:
+            span["flash_live_share"] = share
+        with TraceAnnotation("serve.admit", **span):
             with TraceAnnotation("serve.prefill_dispatch"):
                 logits = self._admit_prefill(slot, req)
             # the ledger charges what the clock has covered — the

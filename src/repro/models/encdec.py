@@ -279,7 +279,8 @@ def prefill_into_cache(cfg: ArchConfig, params: Params,
             p = bp[pos_i]
             q, k, v = T._qkv(cfg, p["attn"], x, positions)
             window = cfg.sliding_window if kind == "local" else 0
-            o = ops.flash_attention(q, k, v, causal=True, window=window)
+            o = ops.flash_attention(q, k, v, length, causal=True,
+                                    window=window)
             x = x + matmul(o.reshape(1, p_len, -1), p["attn"]["wo"])
             states[f"k{pos_i}"] = k.transpose(0, 2, 1, 3)   # (1,KH,P,hd)
             states[f"v{pos_i}"] = v.transpose(0, 2, 1, 3)

@@ -1157,7 +1157,8 @@ def prefill_into_cache(cfg: ArchConfig, params: Params,
             if kind in ("full", "local"):
                 q, k, v = _qkv(cfg, p["attn"], x, positions)
                 window = cfg.sliding_window if kind == "local" else 0
-                o = ops.flash_attention(q, k, v, causal=True, window=window)
+                o = ops.flash_attention(q, k, v, length, causal=True,
+                                        window=window)
                 o = o.reshape(1, p_len, cfg.n_heads * cfg.head_dim_)
                 x = _residual(cfg, x, matmul(o, p["attn"]["wo"]))
                 states[f"k{pos_i}"] = k.transpose(0, 2, 1, 3)  # (1,KH,P,hd)

@@ -5,6 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.kernels import flash_attention as fa
 from repro.kernels import ops, ref
 
 jax.config.update("jax_enable_x64", False)
@@ -28,8 +29,7 @@ def test_flash_attention_matches_ref(b, s, h, kh, hd, dtype):
     q = rand(ks[0], (b, s, h, hd), dtype)
     k = rand(ks[1], (b, s, kh, hd), dtype)
     v = rand(ks[2], (b, s, kh, hd), dtype)
-    out = ops.flash_attention(q, k, v, causal=True, blk_q=64, blk_k=64,
-                              interpret=True)
+    out = ops.flash_attention(q, k, v, causal=True, interpret=True)
     want = ref.mha_reference(q, k, v, causal=True)
     tol = 2e-2 if dtype == "bfloat16" else 2e-5
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -42,8 +42,7 @@ def test_flash_attention_noncausal():
     q = rand(ks[0], (1, 128, 2, 64), "float32")
     k = rand(ks[1], (1, 128, 2, 64), "float32")
     v = rand(ks[2], (1, 128, 2, 64), "float32")
-    out = ops.flash_attention(q, k, v, causal=False, blk_q=64, blk_k=64,
-                              interpret=True)
+    out = ops.flash_attention(q, k, v, causal=False, interpret=True)
     want = ref.mha_reference(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
                                atol=2e-5, rtol=2e-5)
@@ -56,9 +55,121 @@ def test_flash_attention_sliding_window(window):
     k = rand(ks[1], (1, 256, 2, 64), "float32")
     v = rand(ks[2], (1, 256, 2, 64), "float32")
     out = ops.flash_attention(q, k, v, causal=True, window=window,
-                              blk_q=64, blk_k=64, interpret=True)
+                              interpret=True)
     want = ref.mha_reference(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def _prefill_lengths(s, group):
+    """None, the whole sequence, one token, a length inside a KV block
+    and one inside a q tile, from the kernel's own tiles at (s, group)."""
+    blk_q, blk_k = fa.flash_tiles(s, group)
+    return {"none": None, "all": s, "one": 1,
+            "mid_block": (s // blk_k - 1) * blk_k + blk_k // 2 + 1,
+            "mid_tile": (s // blk_q - 1) * blk_q + blk_q // 2 - 1}
+
+
+@pytest.mark.parametrize("length", ["none", "all", "one", "mid_block",
+                                    "mid_tile"])
+@pytest.mark.parametrize("windowed", [False, True])
+@pytest.mark.parametrize("group,kh,hd", [(1, 1, 64), (4, 2, 128),
+                                         (12, 1, 128)])
+@pytest.mark.parametrize("s", [8, 256, 1024])
+def test_flash_prefill_parity(s, group, kh, hd, windowed, length):
+    """Live rows (below `length`) equal the reference's; junk rows are
+    finite.  The tiles, the live-block skipping and the clamped index
+    maps all follow from (s, group, length, window)."""
+    window = s // 4 + 3 if windowed else 0
+    n = _prefill_lengths(s, group)[length]
+    ks = jax.random.split(jax.random.key(7), 3)
+    q = rand(ks[0], (1, s, group * kh, hd), "float32")
+    k = rand(ks[1], (1, s, kh, hd), "float32")
+    v = rand(ks[2], (1, s, kh, hd), "float32")
+    arg = None if n is None else jnp.int32(n)
+    out = np.asarray(ops.flash_attention(q, k, v, arg, causal=True,
+                                         window=window, interpret=True))
+    want = np.asarray(ref.mha_reference(q, k, v, causal=True, window=window))
+    live = s if n is None else n
+    np.testing.assert_allclose(out[:, :live], want[:, :live],
+                               atol=2e-5, rtol=2e-5)
+    assert np.isfinite(out).all()
+
+
+@pytest.mark.parametrize("s,group,causal,windowed", [
+    (8, 1, True, False), (256, 12, True, True), (1024, 12, True, False),
+    (1024, 12, True, True), (1024, 4, True, True), (1024, 1, False, True),
+    (2048, 12, True, True), (4096, 4, True, False)])
+def test_flash_live_blocks_counts_dense_mask(s, group, causal, windowed):
+    """`flash_live_blocks` counts exactly the (q block, kv block) pairs
+    that hold a pair a dense (causal, window, length) mask marks live,
+    with windows and lengths on and beside the tiles' edges."""
+    blk_q, blk_k = fa.flash_tiles(s, group)
+    qpos = np.arange(s)[:, None]
+    kpos = np.arange(s)[None, :]
+    edges = {1, 2, blk_q - 1, blk_q, blk_q + 1, blk_q + 2, blk_k - 1, blk_k,
+             blk_k + 1, blk_k + 2, s // 3 + 5, s - 1}
+    windows = sorted(w for w in edges if 0 < w < s) if windowed else [0]
+    lengths = sorted(n for n in edges | {s // 2, s} if 0 < n <= s)
+    for window in windows:
+        for length in lengths:
+            mask = (qpos < length) & (kpos < length)
+            if causal:
+                mask &= kpos <= qpos
+            if window:
+                mask &= kpos > qpos - window
+            blocks = mask.reshape(s // blk_q, blk_q, s // blk_k, blk_k)
+            want = int(blocks.any(axis=(1, 3)).sum())
+            got = fa.flash_live_blocks(length, s, blk_q, blk_k, causal,
+                                       window)
+            assert got == (want, (s // blk_q) * (s // blk_k)), \
+                (window, length, got)
+
+
+@pytest.mark.parametrize("length", [1, 300, 700])
+def test_flash_prefill_bidirectional_length(length):
+    """Without the causal mask, keys at or past `length` are still seen
+    by no live row: the kernel masks them inside a partly live block
+    and skips the wholly dead ones."""
+    s, group, hd = 1024, 4, 64
+    ks = jax.random.split(jax.random.key(9), 3)
+    q = rand(ks[0], (1, s, group, hd), "float32")
+    k = rand(ks[1], (1, s, 1, hd), "float32")
+    v = rand(ks[2], (1, s, 1, hd), "float32")
+    n = jnp.int32(length)
+    out = ops.flash_attention(q, k, v, n, causal=False, interpret=True)
+    want = ref.mha_reference(q, k, v, n, causal=False)
+    np.testing.assert_allclose(np.asarray(out[:, :length]),
+                               np.asarray(want[:, :length]),
+                               atol=2e-5, rtol=2e-5)
+    assert np.isfinite(np.asarray(out)).all()
+
+
+@pytest.mark.parametrize("group,window,length", [(12, 0, 300),
+                                                 (4, 200, 512),
+                                                 (1, 0, 1)])
+def test_flash_prefill_never_reads_dead_kv_blocks(group, window, length):
+    """K/V blocks wholly past `length` hold NaN: the live rows do not
+    change, so those blocks are neither read nor mixed in."""
+    s, kh, hd = 1024, 1, 128
+    _, blk_k = fa.flash_tiles(s, group)
+    first_dead = -(-length // blk_k) * blk_k
+    assert first_dead < s
+    ks = jax.random.split(jax.random.key(8), 3)
+    q = rand(ks[0], (1, s, group * kh, hd), "float32")
+    k = rand(ks[1], (1, s, kh, hd), "float32")
+    v = rand(ks[2], (1, s, kh, hd), "float32")
+    clean = ops.flash_attention(q, k, v, jnp.int32(length), window=window,
+                                interpret=True)
+    k_nan = k.at[:, first_dead:].set(jnp.nan)
+    v_nan = v.at[:, first_dead:].set(jnp.nan)
+    dirty = ops.flash_attention(q, k_nan, v_nan, jnp.int32(length),
+                                window=window, interpret=True)
+    np.testing.assert_array_equal(np.asarray(dirty[:, :length]),
+                                  np.asarray(clean[:, :length]))
+    want = ref.mha_reference(q, k, v, causal=True, window=window)
+    np.testing.assert_allclose(np.asarray(dirty[:, :length]),
+                               np.asarray(want[:, :length]),
                                atol=2e-5, rtol=2e-5)
 
 

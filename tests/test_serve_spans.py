@@ -17,7 +17,10 @@ import numpy as np
 import pytest
 from jax.profiler import ProfileData
 
-from repro.launch.serve import BatchedServer, Request, SamplingParams
+from repro.configs import get_config
+from repro.kernels import flash_attention as fa
+from repro.launch.serve import (BatchedServer, Request, SamplingParams,
+                                _flash_live_share)
 
 # the host work of one streamed loop iteration that admits a request
 LOOP = ("serve.fill_slots", "serve.admit", "serve.prefill_dispatch",
@@ -98,6 +101,10 @@ def test_loop_spans_nest_and_carry_args(arch, tmp_path):
     assert {(a[3]["rid"], a[3]["prompt_len"]) for a in admits} \
         == {(0, len(p0)), (1, len(p1))}
     assert all(a[3]["bucket"] >= a[3]["prompt_len"] for a in admits)
+    for a in admits:
+        share = _flash_live_share(server.cfg, a[3]["prompt_len"],
+                                  a[3]["bucket"])
+        assert a[3].get("flash_live_share") == share, (arch, a[3])
     seg = sorted(_named(spans, "serve.segment_dispatch"),
                  key=lambda s: s[1])
     assert all(1 <= s[3]["live_rows"] <= 2 for s in seg)
@@ -105,6 +112,20 @@ def test_loop_spans_nest_and_carry_args(arch, tmp_path):
     # off the greedy fast path
     assert (seg[0][3]["live_rows"], seg[0][3]["plain"]) == (2, 0)
     _stamped_in_order(server, 2)
+
+
+def test_flash_live_share_counts_the_kernel_grid():
+    """`serve.admit`'s `flash_live_share`: the kernel's live (q block, kv
+    block) pairs over its grid, at the published widths' tiles; absent
+    for a model without attention."""
+    cfg = get_config("starcoder2_3b")
+    tiles = fa.flash_tiles(2048, cfg.n_heads // cfg.n_kv_heads)
+    live, total = fa.flash_live_blocks(1500, 2048, *tiles)
+    assert _flash_live_share(cfg, 1500, 2048) == live / total
+    assert 0 < live < total
+    assert _flash_live_share(cfg, 2048, 2048) < 1      # causal: ~half
+    assert _flash_live_share(cfg, 8, 8) == 1
+    assert _flash_live_share(get_config("mamba2_370m"), 1500, 2048) is None
 
 
 def test_offload_and_prefix_spans(tmp_path):

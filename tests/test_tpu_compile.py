@@ -11,8 +11,9 @@ not attached, and check that the program really holds the kernel
 
 Widths: starcoder2_3b decode (8 slots, 24 q heads over 2 KV heads,
 head_dim 128, its 16k context, 128-token pages), its 2048-token flash
-prefill and (3072 x 12288) FFN weight; mamba2_370m's SSD scan (32 heads
-x 64, state 128); granite_4_0_h_small's grouped expert matmuls (18 held
+prefill, its 4,096- and 8-token prefills with a traced true length
+(granite's 32-over-8 heads too) and (3072 x 12288) FFN weight;
+mamba2_370m's SSD scan (32 heads x 64, state 128); granite_4_0_h_small's grouped expert matmuls (18 held
 experts of 4096 x 768, decode at 64 slots x top-10 and a 4,096-token
 prefill), its 128-head SSD scan and its 32-over-8-head decode attention.
 The kernel modules are called directly: `ops` would see the CPU backend
@@ -84,10 +85,14 @@ def _decode(extra=False, paged=False, int8=False):
     return fn, shapes
 
 
-def _flash(s):
-    return (fa.flash_attention,
-            [((1, s, H, HD), jnp.bfloat16), ((1, s, KH, HD), jnp.bfloat16),
-             ((1, s, KH, HD), jnp.bfloat16)])
+def _flash(s, h=H, kh=KH, length=False):
+    """Prefill at `s` tokens; with `length`, a traced true length rides
+    as the kernel's scalar operand (the served call)."""
+    shapes = [((1, s, h, HD), jnp.bfloat16), ((1, s, kh, HD), jnp.bfloat16),
+              ((1, s, kh, HD), jnp.bfloat16)]
+    if length:
+        shapes.append(((), jnp.int32))
+    return fa.flash_attention, shapes
 
 
 def _ssd(s):
@@ -155,6 +160,13 @@ CASES = {
          ((B, KH, S, HD), jnp.bfloat16), ((B, S), jnp.bool_)]),
     "flash_attention.prefill": lambda: _flash(PREFILL),
     "flash_attention.short_prompt": lambda: _flash(8),
+    "flash_attention.starcoder_4096_length": lambda: _flash(4096,
+                                                            length=True),
+    "flash_attention.starcoder_short_length": lambda: _flash(8, length=True),
+    "flash_attention.granite_4096_length": lambda: _flash(
+        4096, G_H, G_KH, length=True),
+    "flash_attention.granite_short_length": lambda: _flash(
+        8, G_H, G_KH, length=True),
     "ssd_scan.prefill": lambda: _ssd(PREFILL),
     "ssd_scan.short_prompt": lambda: _ssd(8),
     "quant_matmul.q8_0.decode": lambda: _quant("q8_0", B),
